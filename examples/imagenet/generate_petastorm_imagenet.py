@@ -19,12 +19,14 @@ SYNTHETIC_NOUNS = {'n01440764': 'tench', 'n01443537': 'goldfish', 'n01484850': '
 
 
 def synthetic_imagenet_rows(images_per_class=4, seed=0, hw=(96, 128)):
+    """Random RGB rows for every synthetic noun; ``hw`` is the inclusive
+    ``(min, max)`` range of each image side."""
     rng = np.random.default_rng(seed)
     rows = []
     for noun_id, text in SYNTHETIC_NOUNS.items():
         for _ in range(images_per_class):
-            h = int(rng.integers(hw[0], hw[1]))
-            w = int(rng.integers(hw[0], hw[1]))
+            h = int(rng.integers(hw[0], hw[1], endpoint=True))
+            w = int(rng.integers(hw[0], hw[1], endpoint=True))
             rows.append({'noun_id': noun_id, 'text': text,
                          'image': rng.integers(0, 255, size=(h, w, 3),
                                                dtype=np.uint8)})
@@ -60,11 +62,13 @@ def _center_resize(image, hw):
 
 
 def generate_petastorm_imagenet(output_url, imagenet_dir=None, synthetic=False,
-                                rowgroup_size_mb=8, dct_hw=None, dct_quality=90):
+                                rowgroup_size_mb=8, dct_hw=None, dct_quality=90,
+                                images_per_class=4, seed=0, hw=(96, 128)):
     """``dct_hw`` switches to the fixed-size DCT-domain store (schema.py
     dct_imagenet_schema): images are resized at write time and stored as quantized DCT
-    coefficient blocks so readers can decode on-chip."""
-    rows = (synthetic_imagenet_rows() if synthetic
+    coefficient blocks so readers can decode on-chip. ``images_per_class``, ``seed``
+    and ``hw`` size the synthetic rows (:func:`synthetic_imagenet_rows`)."""
+    rows = (synthetic_imagenet_rows(images_per_class, seed, hw) if synthetic
             else directory_imagenet_rows(imagenet_dir))
     if dct_hw is not None:
         from examples.imagenet.schema import dct_imagenet_schema

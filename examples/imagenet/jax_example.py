@@ -16,41 +16,11 @@ import numpy as np
 import optax
 
 from examples.imagenet.schema import ImagenetSchema  # noqa: F401  (schema parity anchor)
+from examples.imagenet.transforms import IMAGE_HW, make_label_transform, make_transform
 from petastorm_tpu import make_reader
 from petastorm_tpu.models.resnet import ResNet
 from petastorm_tpu.ops.image import normalize_image, random_crop_flip
 from petastorm_tpu.parallel.loader import JaxDataLoader
-from petastorm_tpu.transform import TransformSpec
-
-IMAGE_HW = 64
-
-
-def make_transform(class_to_label, image_hw=IMAGE_HW):
-    from examples.imagenet.generate_petastorm_imagenet import _center_resize
-
-    def _transform(row):
-        row['image'] = _center_resize(row['image'], image_hw)
-        row['label'] = np.int32(class_to_label[row['noun_id']])
-        return row
-
-    return TransformSpec(_transform,
-                         edit_fields=[('image', np.uint8, (image_hw, image_hw, 3), False),
-                                      ('label', np.int32, (), False)],
-                         selected_fields=['image', 'label'])
-
-
-def make_label_transform(class_to_label, image_field_spec):
-    """Label mapping for a fixed-size store (DCT or raw): keeps the image field as-is
-    (host decode already yields a static shape — or raw coefficient blocks under a
-    field override) and adds the integer label."""
-    def _transform(row):
-        row['label'] = np.int32(class_to_label[row['noun_id']])
-        return row
-
-    return TransformSpec(_transform,
-                         edit_fields=[image_field_spec, ('label', np.int32, (), False)],
-                         selected_fields=['image', 'label'])
-
 
 def train(dataset_url, batch_size=8, epochs=1, learning_rate=1e-3,
           stage_sizes=(1, 1, 1, 1), num_filters=16, on_chip_decode=False,

@@ -215,17 +215,18 @@ def make_model(mesh):
     """The shared TransformerLM with ring attention injected over the mesh's ``seq``
     axis — the model family's documented sequence-parallel injection point
     (petastorm_tpu/models/transformer.py); the model itself stays mesh-agnostic."""
+    import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
     from petastorm_tpu.models import TransformerLM
     from petastorm_tpu.ops.ring_attention import ring_attention
-    from petastorm_tpu.parallel.mesh import shard_map_compat
 
     attn_spec = P('data', 'seq', None, None)
-    ring = shard_map_compat(
+    ring = jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, axis_name='seq', causal=True),
-        mesh, (attn_spec, attn_spec, attn_spec), attn_spec)
+        mesh=mesh, in_specs=(attn_spec, attn_spec, attn_spec), out_specs=attn_spec,
+        check_vma=False)
     return TransformerLM(vocab=VOCAB, embed=EMBED, heads=HEADS, layers=1,
                          dtype=jnp.float32, attention_fn=ring)
 

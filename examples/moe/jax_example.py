@@ -76,7 +76,7 @@ def train_moe(dataset_url, batch_size=8, epochs=2, expert_axis_size=None,
     reader = make_reader(dataset_url, schema_fields=['tokens'], num_epochs=epochs,
                          shuffle_row_groups=True, seed=7)
     loss = params = opt_state = None
-    with mesh:
+    with jax.set_mesh(mesh):
         with JaxDataLoader(reader, batch_size=batch_size, mesh=mesh,
                            partition_spec=P('data')) as loader:
             for step, batch in enumerate(loader):
@@ -160,7 +160,7 @@ def train_pipeline(dataset_url, n_stages=4, batch_size=8, n_micro=2, epochs=2,
     reader = make_reader(dataset_url, schema_fields=['tokens'], num_epochs=epochs,
                          shuffle_row_groups=True, seed=7)
     loss = params = opt_state = None
-    with mesh:
+    with jax.set_mesh(mesh):
         with JaxDataLoader(reader, batch_size=batch_size, mesh=mesh,
                            partition_spec=P('data')) as loader:
             for step, batch in enumerate(loader):

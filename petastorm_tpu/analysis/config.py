@@ -60,7 +60,7 @@ CLOCK_DISCIPLINED_FILES: Tuple[str, ...] = ('resilience.py',
 WORKER_DIR: str = 'workers'
 
 #: basenames of data-path modules where ``raise Exception(...)`` /
-#: ``raise BaseException(...)`` are findings (use the errors.py taxonomy)
+#: ``raise BaseException(...)`` are findings (use the errors.py hierarchy)
 DATAPATH_FILES: Tuple[str, ...] = ('reader_worker.py', 'reader.py',
                                    'cache.py', 'fs_utils.py',
                                    'resilience.py', 'cost_schedule.py',

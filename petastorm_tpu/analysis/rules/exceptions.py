@@ -1,5 +1,5 @@
 """exception-hygiene: broad excepts must justify themselves; data-path raises
-must use the errors.py taxonomy.
+must use the errors.py hierarchy.
 
 Two sub-checks:
 
@@ -21,10 +21,10 @@ can do:
   not enough: a worker loop that eats an exception keeps publishing results
   from unknown state, so the reason must be written at the site.
 
-**Raise taxonomy.** In the data-path modules (``config.DATAPATH_FILES`` and
+**Raise hierarchy.** In the data-path modules (``config.DATAPATH_FILES`` and
 everything under ``workers/``), ``raise Exception(...)`` /
 ``raise BaseException(...)`` are findings: generic raises carry zero
-machine-readable structure, while the :mod:`petastorm_tpu.errors` taxonomy
+machine-readable structure, while the :mod:`petastorm_tpu.errors` hierarchy
 is what the retry classifier, quarantine ledger and doctor key on.
 """
 
@@ -115,12 +115,12 @@ def body_logs(stmts: Sequence[ast.stmt]) -> bool:
 
 
 class ExceptionHygieneRule(Rule):
-    """Broad-except and raise-taxonomy checks (module doc)."""
+    """Broad-except and raise-hierarchy checks (module doc)."""
 
     name = 'exception-hygiene'
     description = ('broad excepts that can swallow need a reason comment '
                    '(workers/) or at least logging (elsewhere); data-path '
-                   'raises must use the errors.py taxonomy, not bare '
+                   'raises must use the errors.py hierarchy, not bare '
                    'Exception')
 
     def check_module(self, module: SourceModule,

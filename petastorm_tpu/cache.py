@@ -13,7 +13,7 @@ Two on-disk value formats:
   of the process-pool wire, ``workers/serializers.py``) plus a pickled sidecar for
   non-Arrow columns, in a single atomically-renamed file. A hit MEMORY-MAPS the file
   and serves the numeric columns as read-only zero-copy views straight into the
-  consumer (e.g. ``JaxDataLoader``'s coalesced-upload path) — no Parquet read, no
+  consumer (e.g. ``JaxDataLoader``'s upload) — no Parquet read, no
   decode, no unpickle, no copy. Non-columnar values degrade to an embedded pickle
   record transparently (``stats['pickle_hits']`` makes the degradation visible).
 

@@ -12,6 +12,7 @@ CompressedNdarrayCodec, CompressedImageCodec), re-designed for a TPU-first stack
 
 import os
 import threading
+import zipfile
 import zlib
 from io import BytesIO
 
@@ -494,9 +495,29 @@ def _cached_npy_meta(payload, cache):
 
 class CompressedNdarrayCodec(FieldCodec):
     """Stores a numpy tensor zlib-compressed via ``np.savez_compressed`` (reference:
-    petastorm/codecs.py:174-212)."""
+    petastorm/codecs.py:174-212).
+
+    :param compresslevel: None (default) writes exactly what ``np.savez_compressed``
+        writes (zlib's default level). An int 0-9 writes the same ``.npz`` container
+        at that deflate level. Level 0 leaves every deflate block *stored*, which
+        any zip reader opens and which ``make_reader(device_decode_fields=...)``
+        inflates on the accelerator (``ops/raw_decode.stored_inflate``) instead of
+        on the host; Huffman-coded frames always inflate on the host."""
 
     codec_name = 'compressed_ndarray'
+    _compresslevel = None  # instances restored without __init__ keep the default
+
+    def __init__(self, compresslevel=None):
+        if compresslevel is not None:
+            compresslevel = int(compresslevel)
+            if not 0 <= compresslevel <= 9:
+                raise ValueError('compresslevel must be None or 0-9, got {}'
+                                 .format(compresslevel))
+        self._compresslevel = compresslevel
+
+    @property
+    def compresslevel(self):
+        return self._compresslevel
 
     def encode(self, unischema_field, value):
         expected = np.dtype(unischema_field.numpy_dtype)
@@ -507,8 +528,30 @@ class CompressedNdarrayCodec(FieldCodec):
             raise ValueError('Unexpected shape {} for field {} (expected {})'
                              .format(value.shape, unischema_field.name, unischema_field.shape))
         memfile = BytesIO()
-        np.savez_compressed(memfile, arr=value)
+        if self._compresslevel is None:
+            np.savez_compressed(memfile, arr=value)
+        else:
+            # np.savez_compressed's container, at the chosen level
+            with zipfile.ZipFile(memfile, 'w', zipfile.ZIP_DEFLATED,
+                                 compresslevel=self._compresslevel) as archive:
+                with archive.open('arr.npy', 'w', force_zip64=True) as member:
+                    np.lib.format.write_array(member, value, allow_pickle=False)
         return memfile.getvalue()
+
+    def to_config(self):
+        config = {'codec': self.codec_name}
+        if self._compresslevel is not None:
+            config['compresslevel'] = self._compresslevel
+        return config
+
+    @classmethod
+    def from_config(cls, config):
+        return cls(compresslevel=config.get('compresslevel'))
+
+    def __str__(self):
+        if self._compresslevel is None:
+            return 'CompressedNdarrayCodec()'
+        return 'CompressedNdarrayCodec(compresslevel={})'.format(self._compresslevel)
 
     def decode(self, unischema_field, value):
         memfile = BytesIO(value)

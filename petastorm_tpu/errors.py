@@ -3,11 +3,11 @@ petastorm/etl/dataset_metadata.py PetastormMetadataError).
 
 The resilience subsystem (petastorm_tpu/resilience.py, docs/robustness.md) splits
 failures into two classes: *transient* (retryable — network hiccups, throttled object
-stores, flaky tunnels) and *permanent* (corrupt data, schema bugs). ``TransientIOError``
+stores, flaky links) and *permanent* (corrupt data, schema bugs). ``TransientIOError``
 marks the former explicitly; ``QuarantinedRowGroupError`` reports a rowgroup that was
 skipped under ``on_error='skip'`` and landed in the quarantine ledger.
 
-Strict-typed (mypy.ini ``[mypy-petastorm_tpu.errors]``): the taxonomy is the
+Strict-typed (mypy.ini ``[mypy-petastorm_tpu.errors]``): the hierarchy is the
 machine-readable contract the retry classifier, ledger and doctor key on, so
 its structured attributes carry full signatures.
 """
@@ -51,7 +51,7 @@ class MetadataError(PetastormTpuError):
 
 class TransientIOError(PetastormTpuError, OSError):
     """An IO failure that is expected to succeed on retry (connection reset, throttled
-    object store, wedged tunnel). Subclasses ``OSError`` so generic IO-error handling
+    object store, wedged link). Subclasses ``OSError`` so generic IO-error handling
     (and the default transient classifier in :mod:`petastorm_tpu.resilience`) treats it
     uniformly with errno-style failures; raise it from custom filesystems to opt an
     error into the retry path explicitly."""

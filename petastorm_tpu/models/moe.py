@@ -39,23 +39,10 @@ def _capacity(num_tokens, num_experts, num_selected, capacity_factor):
 def _ambient_mesh_axes():
     """Axis names of the mesh context the caller is tracing under, or None when no
     mesh context is active (plain single-chip execution)."""
-    try:
-        from jax.sharding import get_abstract_mesh
-        mesh = get_abstract_mesh()
-        if mesh is not None and mesh.axis_names:
-            return set(mesh.axis_names)
-    except (ImportError, AttributeError):
-        pass
-    try:
-        # Private-API fallback for older jax: a rename that keeps the module but
-        # moves an attribute must degrade to the no-mesh path, not raise from
-        # inside every forward pass (ADVICE r3).
-        from jax._src.mesh import thread_resources
-        mesh = thread_resources.env.physical_mesh
-        if mesh.axis_names:
-            return set(mesh.axis_names)
-    except (ImportError, AttributeError):
-        pass
+    from jax.sharding import get_abstract_mesh
+    mesh = get_abstract_mesh()
+    if mesh.axis_names:
+        return set(mesh.axis_names)
     return None
 
 
@@ -75,8 +62,8 @@ def _sharding_hint(x, spec_axes):
     if axes is None:
         warnings.warn(
             'MoE expert_axis={!r} set but no mesh context is active; the expert '
-            'sharding hint was skipped. Trace under `with mesh:` (or jax.set_mesh)'
-            ' for expert parallelism.'.format(spec_axes[0]), stacklevel=2)
+            'sharding hint was skipped. Trace under `with jax.set_mesh(mesh):` for '
+            'expert parallelism.'.format(spec_axes[0]), stacklevel=2)
         return x
     wanted = {a for a in spec_axes if a is not None}
     if not wanted <= axes:

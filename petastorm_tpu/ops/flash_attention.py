@@ -1,15 +1,23 @@
 """Pallas TPU flash attention: O(T)-memory blockwise attention on the MXU.
 
 Forward pass is a Pallas kernel (grid over [batch*heads, q-blocks, kv-blocks], online
-log-sum-exp softmax accumulated in VMEM scratch, matmuls in fp32 on the MXU) that also
+log-sum-exp softmax accumulated in VMEM scratch, f32 accumulation on the MXU; float32
+inputs contract at fp32, bf16 inputs in one bf16 pass) that also
 emits the per-row log-sum-exp. Backward is the flash backward: two Pallas kernels (dQ,
 and dK/dV) that REMATERIALIZE the score blocks from Q/K and the saved LSE — the
 [T, T] attention matrix never exists in any pass, so training memory is O(T * block),
 sub-quadratic in sequence length.
 
 Falls back to the XLA path (:func:`petastorm_tpu.ops.ring_attention.dense_attention`)
-when shapes don't tile (T % block != 0, head_dim not lane-aligned) and runs in Pallas
-interpret mode on CPU so tests exercise the same kernel logic without a TPU.
+when shapes don't tile (T % block != 0, head_dim not lane-aligned). The kernels compile
+for the TPU and run in Pallas interpret mode on the CPU backend only
+(:func:`pallas_interpret`), so CPU tests exercise the same kernel logic.
+
+Per-row operands keep the TPU block rule (last two block dims divisible by (8, 128)
+or equal to the array's): the log-sum-exp, the backward's ``delta`` and the query
+segment ids ride as ``[*, T, 1]`` columns (block ``(1, block_q, 1)``), the key
+segment ids as ``[B, 1, T]`` rows (block ``(1, 1, block_k)``), so every in-kernel
+broadcast is against a ``[Bq, 1]`` column or a ``[1, Bk]`` row with no transpose.
 
 No reference analog (petastorm is data-layer only; SURVEY.md §5.7) — this is the compute
 side of the long-context story next to :mod:`petastorm_tpu.ops.ring_attention`.
@@ -24,25 +32,47 @@ _NEG_INF = -1e30
 _LANE = 128
 
 
-def _tpu_compiler_params(pltpu, dimension_semantics):
-    """jax API-drift shim: pallas TPU compiler params were named
-    ``TPUCompilerParams`` before jax 0.4.34-era releases renamed the class to
-    ``CompilerParams``. Resolve whichever this jax ships so the kernels work (and
-    the 13 flash tests stay green) across the drift."""
-    cls = getattr(pltpu, 'CompilerParams', None) or pltpu.TPUCompilerParams
-    return cls(dimension_semantics=dimension_semantics)
+def pallas_interpret():
+    """Whether this process's Pallas kernels run in interpret mode: on the CPU
+    backend only. A TPU compiles them; any other backend is an error, never a
+    silent interpreter run."""
+    backend = jax.default_backend()
+    if backend == 'tpu':
+        return False
+    if backend == 'cpu':
+        return True
+    raise RuntimeError('Pallas TPU kernels need a tpu (compiled) or cpu '
+                       '(interpret) backend, not {!r}'.format(backend))
+
+
+def _compiler_params(*dimension_semantics):
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
+
+
+def _dot_precision(dtype):
+    """Contraction precision of the kernels' matmuls: float32 inputs ask Mosaic
+    for fp32 contraction (``HIGHEST``) — its default lets the MXU take f32
+    operands in one bf16 pass; bf16 inputs keep the one-pass default."""
+    if jnp.dtype(dtype) == jnp.float32:
+        return jax.lax.Precision.HIGHEST
+    return jax.lax.Precision.DEFAULT
+
+
+def _dot(a, b, contract, precision):
+    """``dot_general`` contracting dims ``contract`` with f32 accumulation."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=precision,
+                               preferred_element_type=jnp.float32)
 
 
 def _block_segment_mask(qseg, kseg):
-    """[Bq], [Bk] int32 -> [Bq, Bk] bool: same packed segment, both non-padding
-    (``ops.packing`` convention: 0 = padding)."""
-    same = qseg[:, None] == kseg[None, :]
-    valid = (qseg[:, None] > 0) & (kseg[None, :] > 0)
-    return same & valid
+    """[Bq, 1] column, [1, Bk] row of int32 ids -> [Bq, Bk] bool: same packed
+    segment, both non-padding (``ops.packing`` convention: 0 = padding)."""
+    return (qseg == kseg) & (qseg > 0) & (kseg > 0)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, *rest, causal, segmented, block_q, block_k,
-                  scale):
+                  scale, precision):
     """One (bh, qi, ki) grid step: fold K/V block ``ki`` into the online softmax
     accumulator for Q block ``qi``. With ``segmented``, two extra int32 refs carry
     the packed-segment ids and attention is confined within segments."""
@@ -69,8 +99,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, causal, segmented, block_q, block_
         q = q_ref[0].astype(jnp.float32)                       # [Bq, D]
         k = k_ref[0].astype(jnp.float32)                       # [Bk, D]
         v = v_ref[0].astype(jnp.float32)                       # [Bk, D]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale  # [Bq, Bk]
+        s = _dot(q, k, ((1,), (1,)), precision) * scale          # [Bq, Bk]
         if causal:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -86,11 +115,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, causal, segmented, block_q, block_
         if segmented:
             # A fully-masked row has every s at _NEG_INF and would get p == 1
             # everywhere (exp(0)); zero those so empty rows accumulate nothing.
-            p = p * (s > _NEG_INF / 2)
+            p = jnp.where(s > _NEG_INF / 2, p, 0.0)
         corr = jnp.exp(m_prev - m_new)                         # [Bq, 1]
         l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        acc_scr[:] = acc_scr[:] * corr + _dot(p, v, ((1,), (0,)), precision)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
@@ -113,18 +141,35 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, causal, segmented, block_q, block_
                 nonempty, acc_scr[:] / jnp.where(nonempty, l, 1.0), 0.0
             ).astype(o_ref.dtype)
             lse_ref[0] = jnp.where(nonempty, m_scr[:, :1] + jnp.log(
-                jnp.where(nonempty, l, 1.0)), 0.0)[:, 0]
+                jnp.where(nonempty, l, 1.0)), 0.0)
         else:
             o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
             # log-sum-exp per query row: the backward's softmax replay key
-            lse_ref[0] = (m_scr[:, :1] + jnp.log(l_scr[:, :1]))[:, 0]
+            lse_ref[0] = m_scr[:, :1] + jnp.log(l_scr[:, :1])
+
+
+def _segment_operands(segments, block_q, block_k, heads, kv_outer):
+    """Block specs and operands for the [B, T] packed-segment ids (shared across
+    the ``heads`` interleaved into the BH dim): a [B, T, 1] column for the query
+    block and a [B, 1, T] row for the key block. The grid is (bh, q-block,
+    k-block), or (bh, k-block, q-block) with ``kv_outer``."""
+    from jax.experimental import pallas as pl
+    h = heads
+    if kv_outer:
+        qmap = lambda b, i, j: (b // h, j, 0)  # noqa: E731
+        kmap = lambda b, i, j: (b // h, 0, i)  # noqa: E731
+    else:
+        qmap = lambda b, i, j: (b // h, i, 0)  # noqa: E731
+        kmap = lambda b, i, j: (b // h, 0, j)  # noqa: E731
+    specs = [pl.BlockSpec((1, block_q, 1), qmap),
+             pl.BlockSpec((1, 1, block_k), kmap)]
+    return specs, [segments[:, :, None], segments[:, None, :]]
 
 
 def _flash_forward(q, k, v, causal, block_q, block_k, interpret, segments=None,
                    heads=None):
-    """q/k/v: [BH, T, D] -> (o: [BH, T, D], lse: [BH, T] float32). ``segments`` is
-    the [B, T] int32 packed-segment array (shared across the ``heads`` interleaved
-    into the BH dim)."""
+    """q/k/v: [BH, T, D] -> (o: [BH, T, D], lse: [BH, T, 1] float32). ``segments``
+    is the [B, T] int32 packed-segment array."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -134,7 +179,8 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret, segments=None,
     scale = d ** -0.5
     segmented = segments is not None
     kernel = functools.partial(_flash_kernel, causal=causal, segmented=segmented,
-                               block_q=block_q, block_k=block_k, scale=scale)
+                               block_q=block_q, block_k=block_k, scale=scale,
+                               precision=_dot_precision(q.dtype))
     grid = (bh, nq, nk)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -143,56 +189,52 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret, segments=None,
     ]
     operands = [q, k, v]
     if segmented:
-        h = heads
-        in_specs += [
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b // h, i)),
-            pl.BlockSpec((1, block_k), lambda b, i, j: (b // h, j)),
-        ]
-        operands += [segments, segments]
+        seg_specs, seg_operands = _segment_operands(segments, block_q, block_k,
+                                                    heads, False)
+        in_specs += seg_specs
+        operands += seg_operands
     return pl.pallas_call(
         kernel,
         out_shape=[jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-                   jax.ShapeDtypeStruct((bh, t), jnp.float32)],
+                   jax.ShapeDtypeStruct((bh, t, 1), jnp.float32)],
         grid=grid,
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-                   pl.BlockSpec((1, block_q), lambda b, i, j: (b, i))],
+                   pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANE), jnp.float32),   # running max (lane-replicated)
             pltpu.VMEM((block_q, _LANE), jnp.float32),   # running denominator
             pltpu.VMEM((block_q, d), jnp.float32),       # output accumulator
         ],
-        compiler_params=_tpu_compiler_params(
-            pltpu, ('parallel', 'parallel', 'arbitrary')),
+        compiler_params=_compiler_params('parallel', 'parallel', 'arbitrary'),
         interpret=interpret,
     )(*operands)
 
 
 def _rematerialized_p_ds(q, k, v, do, lse, delta, qi, ki, causal, block_q, block_k,
-                         scale, seg_mask=None):
+                         scale, precision, seg_mask=None):
     """Shared backward-block math: replay P from (Q, K, LSE), form dS.
 
     Returns (p, ds), both [Bq, Bk] fp32. ``delta = rowsum(dO * O)`` is the softmax
-    jacobian's diagonal correction (flash-attention backward identity).
+    jacobian's diagonal correction (flash-attention backward identity); ``lse``
+    and ``delta`` are [Bq, 1] columns.
     ``seg_mask`` re-applies the forward's segment confinement (the replayed
     exp(s - lse) is only meaningful where the forward attended)."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    p = jnp.exp(s - lse[:, None])                               # [Bq, Bk]
+    s = _dot(q, k, ((1,), (1,)), precision) * scale
+    p = jnp.exp(s - lse)                                        # [Bq, Bk]
     if causal:
         q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
         k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
         p = jnp.where(q_pos >= k_pos, p, 0.0)
     if seg_mask is not None:
         p = jnp.where(seg_mask, p, 0.0)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # [Bq, Bk]
-    ds = p * (dp - delta[:, None])
+    dp = _dot(do, v, ((1,), (1,)), precision)                  # [Bq, Bk]
+    ds = p * (dp - delta)
     return p, ds
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                         causal, segmented, block_q, block_k, scale):
+                         causal, segmented, block_q, block_k, scale, precision):
     """Grid (bh, qi, ki): accumulate dQ for q-block qi over all k-blocks."""
     from jax.experimental import pallas as pl
 
@@ -217,10 +259,9 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         seg_mask = (_block_segment_mask(qseg_ref[0], kseg_ref[0])
                     if segmented else None)
         _, ds = _rematerialized_p_ds(q, k, v, do, lse_ref[0], delta_ref[0], qi, ki,
-                                     causal, block_q, block_k, scale, seg_mask)
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+                                     causal, block_q, block_k, scale, precision,
+                                     seg_mask)
+        dq_scr[:] = dq_scr[:] + _dot(ds, k, ((1,), (0,)), precision) * scale
 
     if causal:
         @pl.when(ki * block_k <= qi * block_q + (block_q - 1))
@@ -235,7 +276,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                          causal, segmented, block_q, block_k, scale):
+                          causal, segmented, block_q, block_k, scale, precision):
     """Grid (bh, ki, qi): accumulate dK/dV for k-block ki over all q-blocks."""
     from jax.experimental import pallas as pl
 
@@ -261,12 +302,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest
         seg_mask = (_block_segment_mask(qseg_ref[0], kseg_ref[0])
                     if segmented else None)
         p, ds = _rematerialized_p_ds(q, k, v, do, lse_ref[0], delta_ref[0], qi, ki,
-                                     causal, block_q, block_k, scale, seg_mask)
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+                                     causal, block_q, block_k, scale, precision,
+                                     seg_mask)
+        dv_scr[:] = dv_scr[:] + _dot(p, do, ((0,), (0,)), precision)
+        dk_scr[:] = dk_scr[:] + _dot(ds, q, ((0,), (0,)), precision) * scale
 
     if causal:
         # q-blocks entirely above the diagonal (every q_pos < k_pos) contribute nothing
@@ -284,7 +323,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest
 
 def _flash_backward(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
                     segments=None, heads=None):
-    """q/k/v/o/do: [BH, T, D], lse: [BH, T] -> (dq, dk, dv), blockwise (no [T, T])."""
+    """q/k/v/o/do: [BH, T, D], lse: [BH, T, 1] -> (dq, dk, dv), blockwise (no [T, T])."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -292,47 +331,50 @@ def _flash_backward(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
     nq, nk = t // block_q, t // block_k
     scale = d ** -0.5
     segmented = segments is not None
+    precision = _dot_precision(q.dtype)
     # Softmax jacobian diagonal: delta_i = sum_d dO_id * O_id (O(T*D), no score matrix).
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)  # [BH, T]
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)                                # [BH, T, 1]
 
     qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
-    qrow = pl.BlockSpec((1, block_q), lambda b, i, j: (b, i))
+    qcol = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
 
-    dq_in_specs = [qspec, kspec, kspec, qspec, qrow, qrow]
+    dq_in_specs = [qspec, kspec, kspec, qspec, qcol, qcol]
     dq_operands = [q, k, v, do, lse, delta]
     if segmented:
-        h = heads
-        dq_in_specs += [pl.BlockSpec((1, block_q), lambda b, i, j: (b // h, i)),
-                        pl.BlockSpec((1, block_k), lambda b, i, j: (b // h, j))]
-        dq_operands += [segments, segments]
+        seg_specs, seg_operands = _segment_operands(segments, block_q, block_k,
+                                                    heads, False)
+        dq_in_specs += seg_specs
+        dq_operands += seg_operands
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, causal=causal, segmented=segmented,
-                          block_q=block_q, block_k=block_k, scale=scale),
+                          block_q=block_q, block_k=block_k, scale=scale,
+                          precision=precision),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         grid=(bh, nq, nk),
         in_specs=dq_in_specs,
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_tpu_compiler_params(
-            pltpu, ('parallel', 'parallel', 'arbitrary')),
+        compiler_params=_compiler_params('parallel', 'parallel', 'arbitrary'),
         interpret=interpret,
     )(*dq_operands)
 
     # dK/dV iterate the OTHER way: outer over k-blocks, inner over q-blocks.
     kspec_o = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
     qspec_i = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0))
-    qrow_i = pl.BlockSpec((1, block_q), lambda b, i, j: (b, j))
-    dkv_in_specs = [qspec_i, kspec_o, kspec_o, qspec_i, qrow_i, qrow_i]
+    qcol_i = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0))
+    dkv_in_specs = [qspec_i, kspec_o, kspec_o, qspec_i, qcol_i, qcol_i]
     dkv_operands = [q, k, v, do, lse, delta]
     if segmented:
-        h = heads
-        dkv_in_specs += [pl.BlockSpec((1, block_q), lambda b, i, j: (b // h, j)),
-                         pl.BlockSpec((1, block_k), lambda b, i, j: (b // h, i))]
-        dkv_operands += [segments, segments]
+        seg_specs, seg_operands = _segment_operands(segments, block_q, block_k,
+                                                    heads, True)
+        dkv_in_specs += seg_specs
+        dkv_operands += seg_operands
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, causal=causal, segmented=segmented,
-                          block_q=block_q, block_k=block_k, scale=scale),
+                          block_q=block_q, block_k=block_k, scale=scale,
+                          precision=precision),
         out_shape=[jax.ShapeDtypeStruct((bh, t, d), k.dtype),
                    jax.ShapeDtypeStruct((bh, t, d), v.dtype)],
         grid=(bh, nk, nq),
@@ -340,8 +382,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
         out_specs=[kspec_o, kspec_o],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_tpu_compiler_params(
-            pltpu, ('parallel', 'parallel', 'arbitrary')),
+        compiler_params=_compiler_params('parallel', 'parallel', 'arbitrary'),
         interpret=interpret,
     )(*dkv_operands)
     return dq, dk, dv
@@ -419,7 +460,7 @@ def _fwd(q, k, v, causal, block_q, block_k):
     if not use:
         return dense_attention(q, k, v, causal=causal), (q, k, v, None, None, None)
     b, t, h, d = q.shape
-    interpret = jax.default_backend() != 'tpu'
+    interpret = pallas_interpret()
     # Residuals stay in the kernels' [BH, T, D] layout so the backward re-uses the
     # forward's transposes instead of redoing them.
     q_bh, k_bh, v_bh = _to_bh(q), _to_bh(k), _to_bh(v)
@@ -436,12 +477,11 @@ def _bwd(causal, block_q, block_k, residuals, g):
                          q_bh, k_bh, v_bh)
         return vjp(g)
     b, h = bh_dims
-    interpret = jax.default_backend() != 'tpu'
+    interpret = pallas_interpret()
     block_q, block_k = _resolve_blocks(q_bh.shape[1], block_q, block_k)
     dq, dk, dv = _flash_backward(q_bh, k_bh, v_bh, o_bh, lse, _to_bh(g), causal,
                                  block_q, block_k, interpret)
     return _from_bh(dq, b, h), _from_bh(dk, b, h), _from_bh(dv, b, h)
-
 
 
 flash_attention.defvjp(_fwd, _bwd)
@@ -468,7 +508,7 @@ def _seg_fwd(q, k, v, segments, causal, block_q, block_k):
         return (masked_dense_attention(q, k, v, mask),
                 (q, k, v, segments, None, None, None))
     b, t, h, d = q.shape
-    interpret = jax.default_backend() != 'tpu'
+    interpret = pallas_interpret()
     q_bh, k_bh, v_bh = _to_bh(q), _to_bh(k), _to_bh(v)
     o_bh, lse = _flash_forward(q_bh, k_bh, v_bh, causal, block_q, block_k,
                                interpret, segments=segments, heads=h)
@@ -489,7 +529,7 @@ def _seg_bwd(causal, block_q, block_k, residuals, g):
                          q_bh, k_bh, v_bh)
         return vjp(g) + (_seg_zero_cotangent(segments),)
     b, h = bh_dims
-    interpret = jax.default_backend() != 'tpu'
+    interpret = pallas_interpret()
     block_q, block_k = _resolve_blocks(q_bh.shape[1], block_q, block_k)
     dq, dk, dv = _flash_backward(q_bh, k_bh, v_bh, o_bh, lse, _to_bh(g), causal,
                                  block_q, block_k, interpret, segments=segments,

@@ -145,7 +145,9 @@ def dct_decode_images_jax(coeffs, quality=75):
     q = jnp.asarray(quant_tables(quality, channels))
     c = jnp.asarray(_C)
     deq = coeffs.astype(jnp.float32) * q
-    blocks = jnp.einsum('ji,bhwjkc,kl->bhwilc', c, deq, c)
+    # HIGHEST: the TPU's default f32 matmul rounds operands to bf16, which moves
+    # pixels by several levels against the host decode (the parity contract)
+    blocks = jnp.einsum('ji,bhwjkc,kl->bhwilc', c, deq, c, precision='highest')
     b, h8, w8 = blocks.shape[:3]
     x = blocks.transpose(0, 1, 3, 2, 4, 5).reshape(b, h8 * 8, w8 * 8, channels) + 128.0
     if channels == 3:

@@ -10,19 +10,20 @@ live here:
   typed ``(n,) + shape`` array through static slices + ``bitcast_convert_type``
   — pure view-level work XLA fuses into the consuming program, matching
   ``jax.device_put``'s dtype canonicalization exactly (under x32, int64/uint64
-  land as the little-endian low word, like the loader's coalesced unpack).
+  land as the little-endian low word).
 - **deflate-lite** (:func:`parse_stored_deflate_layout`, :func:`plan_stored_batch`,
   :func:`stored_inflate`): raw-deflate streams whose every block is *stored*
-  (BTYPE=00 — what zlib emits for incompressible input, and always what level-0
-  encoding produces) are just framed memcpys; the host parses the 5-byte block
-  headers into a segment table and a Pallas kernel performs the gather-copy on
+  (BTYPE=00 — what ``CompressedNdarrayCodec(compresslevel=0)`` writes; at
+  zlib's default level a member always opens with a Huffman block, because
+  the ``.npy`` header compresses) are just framed memcpys; the host parses the 5-byte block
+  headers into a segment table and one XLA gather performs the copy on
   device. Streams with Huffman-coded blocks return ``None`` from the parser —
   entropy decode is bit-serial and stays on the host (the same split
   ``ops/image_decode.py`` documents for JPEG).
 
-The Pallas kernel runs compiled on TPU and in interpreter mode elsewhere
-(``interpret=None`` resolves like ``ops/flash_attention.py``), so CPU test runs
-exercise the same kernel logic without an accelerator.
+The copy is an XLA gather, not a Pallas kernel: stored payloads start at
+arbitrary byte offsets, and Mosaic can only slice a uint8 HBM ref at multiples
+of its 1024-byte tiling, so a DMA kernel cannot express the copy.
 """
 
 from __future__ import annotations
@@ -30,12 +31,6 @@ from __future__ import annotations
 from typing import Any, List, Optional, Tuple
 
 import numpy as np
-
-#: bytes moved per grid step of the stored-inflate kernel; stored-block payload
-#: segments are chunked to this size on the host so the kernel's VMEM window is
-#: fixed regardless of block sizes (a stored block may span up to 65535 bytes)
-STORED_COPY_WINDOW = 1024
-
 
 # ------------------------------------------------------------------ npy unpack
 
@@ -50,7 +45,7 @@ def bitcast_rows(buf: Any, dtype_str: str, row_shape: Tuple[int, ...],
     payloads canonicalize to their low 4-byte word (little-endian), and
     ``float64`` payloads are rejected — the rounding conversion cannot be
     expressed without 64-bit types, so callers must keep such fields on the
-    host path (the same gate as ``parallel.loader.coalescible_layout``)."""
+    host path."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -131,8 +126,8 @@ def plan_stored_batch(
     """Build the device copy plan for a batch of raw-deflate frames that are
     ALL stored-block-only: returns ``(segments, frame_lengths)`` where
     ``segments`` is an ``(m, 3)`` int32 table of ``(src_offset, dst_offset,
-    length)`` chunks (each at most :data:`STORED_COPY_WINDOW` bytes — the
-    kernel's fixed VMEM window) with ``src_offset`` indexing the CONCATENATION
+    length)`` stored-block payloads, in ``dst_offset`` order, with
+    ``src_offset`` indexing the CONCATENATION
     of the frames and ``dst_offset`` the concatenation of their inflated
     payloads, and ``frame_lengths`` the per-frame inflated sizes (callers
     needing a dense ``(n, len)`` view must check they are uniform — a total
@@ -148,11 +143,7 @@ def plan_stored_batch(
             return None
         frame_len = 0
         for src_off, length in layout:
-            start = 0
-            while start < length:
-                chunk = min(STORED_COPY_WINDOW, length - start)
-                rows.append((src_base + src_off + start, dst_base + start, chunk))
-                start += chunk
+            rows.append((src_base + src_off, dst_base, length))
             dst_base += length
             frame_len += length
         frame_lengths.append(frame_len)
@@ -162,74 +153,26 @@ def plan_stored_batch(
     return np.asarray(rows, dtype=np.int32), frame_lengths
 
 
-def _stored_copy_kernel(seg_ref: Any, src_ref: Any, out_ref: Any) -> None:
-    """One grid step = one <=WINDOW-byte chunk: read a fixed window at the
-    chunk's dynamic source offset, read-modify-write it into the output at the
-    destination offset (lanes past ``length`` keep the existing bytes — a later
-    grid step owns them; the grid is sequential, so the RMW overlap at chunk
-    boundaries is ordered). Program 0 zero-fills the output so every
-    read-before-write is defined."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    @pl.when(pl.program_id(0) == 0)
-    def _init() -> None:
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    src_off = seg_ref[0, 0]
-    dst_off = seg_ref[0, 1]
-    length = seg_ref[0, 2]
-    window = src_ref[0, pl.ds(src_off, STORED_COPY_WINDOW)]
-    current = out_ref[0, pl.ds(dst_off, STORED_COPY_WINDOW)]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (STORED_COPY_WINDOW,), 0)
-    out_ref[0, pl.ds(dst_off, STORED_COPY_WINDOW)] = \
-        jnp.where(lane < length, window, current)
-
-
-def stored_inflate(packed_src: Any, segments: Any, out_len: int,
-                   interpret: Optional[bool] = None) -> Any:
-    """Inflate a stored-block-only deflate batch on device: a Pallas gather-copy
-    over the :func:`plan_stored_batch` segment table.
+def stored_inflate(packed_src: Any, segments: Any, out_len: int) -> Any:
+    """Inflate a stored-block-only deflate batch on device: a gather over the
+    :func:`plan_stored_batch` segment table (jit-traceable).
 
     :param packed_src: uint8 ``(s,)`` array — the concatenated raw frames
         (host or device resident).
-    :param segments: int32 ``(m, 3)`` chunk table from :func:`plan_stored_batch`.
+    :param segments: int32 ``(m, 3)`` table from :func:`plan_stored_batch`;
+        zero-length rows (bucket padding) are ignored.
     :param out_len: total inflated length (static).
-    :param interpret: run the kernel in interpreter mode; None resolves to
-        "not on a TPU backend" (same gate as ``ops/flash_attention.py``).
     :returns: uint8 ``(out_len,)`` device array of the inflated payloads.
-
-    The per-step copy window is fixed (:data:`STORED_COPY_WINDOW`), but the
-    whole source and output buffers are staged for the kernel — on a real TPU
-    that staging is VMEM-bounded, so callers must budget total bytes (the
-    loader's device stage caps the path at a few MB per batch and falls back
-    to host inflate above it).
     """
-    import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
 
-    if interpret is None:
-        interpret = jax.default_backend() != 'tpu'
-    m = int(segments.shape[0])
-    if m == 0 or out_len == 0:
+    segments = jnp.asarray(segments, dtype=jnp.int32)
+    if segments.shape[0] == 0 or out_len == 0:
         return jnp.zeros((out_len,), dtype=jnp.uint8)
-    window = STORED_COPY_WINDOW
+    src_off, dst_off, length = segments[:, 0], segments[:, 1], segments[:, 2]
+    # padding rows sort past every output byte, so no byte resolves to one
+    starts = jnp.where(length > 0, dst_off, out_len)
+    pos = jnp.arange(out_len, dtype=jnp.int32)
+    seg = jnp.searchsorted(starts, pos, side='right') - 1
     src = jnp.asarray(packed_src, dtype=jnp.uint8)
-    # pad so every window read/write stays in bounds at the tail
-    src = jnp.pad(src, (0, window))[None, :]
-    out_pad = out_len + window
-
-    out = pl.pallas_call(
-        _stored_copy_kernel,
-        grid=(m,),
-        in_specs=[
-            pl.BlockSpec((1, 3), lambda i: (i, 0)),
-            pl.BlockSpec(src.shape, lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, out_pad), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, out_pad), jnp.uint8),
-        interpret=interpret,
-    )(jnp.asarray(segments, dtype=jnp.int32), src)
-    return out[0, :out_len]
+    return src[src_off[seg] + pos - dst_off[seg]]

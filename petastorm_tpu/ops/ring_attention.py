@@ -125,17 +125,18 @@ def ring_attention_sharded(mesh, seq_axis, causal=False, with_segments=False,
     [B, T, H, D] (segments [B, T] int32, ``ops.packing`` convention)."""
     from jax.sharding import PartitionSpec as P
 
-    from petastorm_tpu.parallel.mesh import shard_map_compat
-
     spec = P(batch_axis, seq_axis, None, None)
     inner = functools.partial(ring_attention, axis_name=seq_axis, causal=causal)
     if with_segments:
         def with_seg(q, k, v, segments):
             return inner(q, k, v, segments=segments)
 
-        return jax.jit(shard_map_compat(
-            with_seg, mesh, (spec, spec, spec, P(batch_axis, seq_axis)), spec))
-    return jax.jit(shard_map_compat(inner, mesh, (spec, spec, spec), spec))
+        return jax.jit(jax.shard_map(
+            with_seg, mesh=mesh,
+            in_specs=(spec, spec, spec, P(batch_axis, seq_axis)), out_specs=spec,
+            check_vma=False))
+    return jax.jit(jax.shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
+                                 out_specs=spec, check_vma=False))
 
 
 def dense_attention(q, k, v, causal=False):

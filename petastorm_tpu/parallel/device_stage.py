@@ -9,7 +9,7 @@ the chip — DCT coefficient blocks run through
 on the MXU), packed ``.npy`` payloads become typed arrays via
 :func:`~petastorm_tpu.ops.raw_decode.bitcast_rows` (static slice + bitcast XLA
 fuses away), and stored-block deflate frames inflate on device through the
-:func:`~petastorm_tpu.ops.raw_decode.stored_inflate` Pallas gather-copy.
+:func:`~petastorm_tpu.ops.raw_decode.stored_inflate` gather.
 Huffman-coded deflate frames inflate on the loader's producer thread — still
 off the contended worker fleet CPU, and the upload stays the packed payload.
 
@@ -20,7 +20,7 @@ Fallback matrix (every cell byte-identical to the host decode path):
   ``DeviceTransform`` chains still run (same jitted math, post-upload) so a
   fallback run trains on the same data an accelerator run would.
 - ``float64`` payloads under x32: per-field host mode (the bitcast cannot
-  express the rounding conversion — same gate as the coalesced upload).
+  express the rounding conversion).
 - accelerator backends require fully-concrete, non-nullable field shapes
   (XLA static shapes); anything else is rejected at loader construction with
   the fix named.
@@ -210,7 +210,6 @@ class DeviceDecodeStage:
         #: float rounding, which is why it is never the CPU default).
         force = os.environ.get('PETASTORM_TPU_DEVICE_DECODE_FORCE') == '1'
         self.host_mode = (not device_put) or (platform == 'cpu' and not force)
-        self.platform = platform
         self._programs: Dict[Tuple[Any, ...], Any] = {}
         self._transform_program: Optional[Any] = None
         self._ring: Deque[Any] = collections.deque()
@@ -321,7 +320,7 @@ class DeviceDecodeStage:
         payloads into upload-ready numeric arrays and build the static recipe
         the jitted finish program is compiled from. Returns
         ``(upload_columns, recipe)`` — upload them through the loader's
-        normal (coalesced/mesh) transfer, then call :meth:`finish`."""
+        normal (single-device/mesh) transfer, then call :meth:`finish`."""
         upload = dict(columns)
         recipe: List[Tuple[Any, ...]] = []
         for plan in self._plans.values():
@@ -390,17 +389,11 @@ class DeviceDecodeStage:
                              '(fortran/object/big-endian)')
         return header_len, dtype.str, tuple(int(d) for d in shape)
 
-    #: byte budget for the on-device stored-inflate path on real TPUs: the
-    #: kernel stages the whole source + output buffers (see raw_decode's
-    #: docstring), so past this total the host-inflate packed path is cheaper
-    #: than blowing VMEM. Interpreted backends have no such staging limit.
-    _STORED_DEVICE_BYTES_MAX = 4 * 1024 * 1024
-
     def _pack_deflate(self, frames: List[Any], enc: np.ndarray,
                       mesh: Any) -> Tuple[Any, ...]:
         """Choose the deflate upload form for this batch: ``('stored', src,
         segs, n, blob_len, npy_meta)`` when every frame is a stored-block
-        stream (the Pallas kernel inflates on device; single-device only — the
+        stream (the gather inflates on device; single-device only — the
         flat source has no batch dim to shard), else ``('packed', matrix)`` —
         host inflate into a ``(n, blob_len)`` npy matrix."""
         from petastorm_tpu.ops.raw_decode import plan_stored_batch
@@ -411,21 +404,17 @@ class DeviceDecodeStage:
                 segs, frame_lengths = plan
                 # dense (n, len) view needs truly uniform payloads — a total
                 # divisible by n does not imply it
-                src_len = sum(len(memoryview(f)) for f in frames)
-                out_len = sum(frame_lengths)
-                fits = (self.platform != 'tpu'
-                        or src_len + out_len <= self._STORED_DEVICE_BYTES_MAX)
                 npy_meta = (self._stored_header_meta(frames[0])
                             if len(set(frame_lengths)) == 1 and frame_lengths[0]
-                            and fits else None)
+                            else None)
                 if npy_meta is not None:
                     src = np.concatenate([np.asarray(f, dtype=np.uint8)
                                           for f in frames])
                     # pad the flat source and the segment table to power-of-two
                     # buckets: compressed sizes differ per batch, and without
                     # bucketing every batch would carry a fresh array layout —
-                    # a fresh coalesced-unpack compile + Pallas grid per batch.
-                    # Zero-length pad segments are no-op RMWs in the kernel.
+                    # a fresh compile per batch. stored_inflate ignores the
+                    # zero-length pad segments.
                     src_pad = 1 << (len(src) - 1).bit_length()
                     src = np.pad(src, (0, src_pad - len(src)))
                     seg_pad = 1 << max(0, (len(segs) - 1).bit_length())
@@ -493,22 +482,18 @@ class DeviceDecodeStage:
         return program(batch, counter)
 
     def _build_program(self, recipe: Tuple[Any, ...]) -> Any:
-        """Compile the jitted finish program for one static recipe. Stored
-        deflate columns pre-inflate through the Pallas kernel OUTSIDE the jit
-        (``pallas_call`` dispatches eagerly), then everything else is one
-        fused program."""
+        """Compile the jitted finish program for one static recipe: every
+        field's decode (and augment) in one fused program."""
         import jax
         from petastorm_tpu.ops.image_decode import dct_decode_images_jax
         from petastorm_tpu.ops.raw_decode import bitcast_rows, stored_inflate
         x64 = self._x64
-        stored_entries = [e for e in recipe if e[0] == 'stored']
-        jit_entries = [e for e in recipe if e[0] != 'stored']
 
         def run(dev: Dict[str, Any]) -> Dict[str, Any]:
             out = {name: col for name, col in dev.items()
                    if name != _RNG_NAME and not name.endswith(_SEGS_SUFFIX)}
             counter = dev.get(_RNG_NAME)
-            for entry in jit_entries:
+            for entry in recipe:
                 if entry[0] == 'dct':
                     _, name, quality, (h, w), squeeze, transform = entry
                     images = dct_decode_images_jax(dev[name], quality=quality)
@@ -525,29 +510,20 @@ class DeviceDecodeStage:
                                 jax.random.PRNGKey(transform.seed), counter)
                         images = transform.apply(images, rng)
                     out[name] = images
+                elif entry[0] == 'stored':
+                    _, name, n, blob_len, header_len, dtype_str, row_shape = entry
+                    flat = stored_inflate(dev[name], dev[name + _SEGS_SUFFIX],
+                                          n * blob_len)
+                    out[name] = bitcast_rows(
+                        flat.reshape(n, blob_len)[:, header_len:], dtype_str,
+                        row_shape, x64=x64)
                 else:
                     _, name, header_len, dtype_str, row_shape = entry
                     out[name] = bitcast_rows(dev[name][:, header_len:],
                                              dtype_str, row_shape, x64=x64)
             return out
 
-        jitted = jax.jit(run)
-
-        if not stored_entries:
-            return jitted
-
-        def with_stored(dev: Dict[str, Any]) -> Dict[str, Any]:
-            dev = dict(dev)
-            for entry in stored_entries:
-                _, name, n, blob_len, header_len, dtype_str, row_shape = entry
-                flat = stored_inflate(dev[name], dev.pop(name + _SEGS_SUFFIX),
-                                      n * blob_len)
-                matrix = flat.reshape(n, blob_len)
-                dev[name] = bitcast_rows(matrix[:, header_len:], dtype_str,
-                                         row_shape, x64=x64)
-            return jitted(dev)
-
-        return with_stored
+        return jax.jit(run)
 
     # ----------------------------------------------------------------- ring
 

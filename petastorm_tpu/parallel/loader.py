@@ -17,7 +17,6 @@ torch), designed per SURVEY.md §7.1 item 5:
 """
 
 import collections
-import contextlib
 import queue
 import sys
 import threading
@@ -25,6 +24,7 @@ import time
 import warnings
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from petastorm_tpu.telemetry import tracing as _flight
 from petastorm_tpu.parallel.shuffling_buffer import (NoopShufflingBuffer,
@@ -33,24 +33,13 @@ from petastorm_tpu.parallel.shuffling_buffer import (NoopShufflingBuffer,
 _END = object()
 #: scan_stream keeps this many compiled (step_fn, chunk-shape) programs per loader
 _SCAN_STREAM_CACHE_MAX = 8
-#: coalesced-upload unpack programs kept per loader (layouts are stable per stream;
-#: the cap only guards pathological consumers feeding ever-changing schemas)
-_UNPACK_CACHE_MAX = 8
-
-
-try:
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except ImportError:  # pragma: no cover - jax is a hard dep in practice
-    _TraceAnnotation = None
 
 
 def _trace_span(name):
     """jax.profiler annotation so loader stages show up in device traces next to the
     XLA ops they feed (SURVEY.md §5.1: the TPU-native replacement for the reference's
-    per-thread cProfile); a no-op nullcontext when jax is absent."""
-    if _TraceAnnotation is None:
-        return contextlib.nullcontext()
-    return _TraceAnnotation(name)
+    per-thread cProfile)."""
+    return TraceAnnotation(name)
 
 
 class LoaderStats(object):
@@ -61,10 +50,8 @@ class LoaderStats(object):
     consumer thread (per-batch accounting) and its producer thread (reader-stat
     mirroring), so bare ``stats.field += 1`` would lose updates under the race.
     ``as_dict`` snapshots every field under the same lock (one consistent view).
-    The upload-mode counters make the H2D path observable in captured
-    bench lines: a hardware capture can PROVE whether the coalesced
-    single-transfer path engaged (``coalesced_uploads``) or each field shipped
-    separately (``per_field_uploads`` — also counts mesh-path uploads).
+    ``per_field_uploads`` counts batches uploaded field by field (every
+    ``device_put`` batch, mesh path included).
 
     ``io_retries`` / ``rowgroups_quarantined`` mirror the reader's resilience
     counters (docs/robustness.md) into the loader's own stats surface: a training
@@ -84,17 +71,14 @@ class LoaderStats(object):
     counts batches whose raw-shipped fields decoded as device kernels;
     ``device_fallback_batches`` counts chunks whose device fields decoded on
     the host instead (CPU backend, ``device_put=False``, or a per-field
-    fallback) — a capture can PROVE which path ran. ``unpack_cache_evictions``
-    counts compiled coalesced-upload unpack programs evicted from the
-    per-loader LRU: non-zero means the consumer feeds more distinct batch
-    layouts than the cache holds, and uploads are paying re-trace cost."""
+    fallback) — a capture can PROVE which path ran."""
 
     _FIELDS = ('batches', 'rows', 'wait_time_s', 'total_time_s',
-               'coalesced_uploads', 'per_field_uploads', 'io_retries',
+               'per_field_uploads', 'io_retries',
                'rowgroups_quarantined', 'cache_hits', 'cache_misses',
                'shm_batches', 'shm_fallback_batches',
                'wire_bytes_copied_per_batch', 'device_decode_batches',
-               'device_fallback_batches', 'unpack_cache_evictions')
+               'device_fallback_batches')
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -102,7 +86,6 @@ class LoaderStats(object):
         self.rows = 0
         self.wait_time_s = 0.0
         self.total_time_s = 0.0
-        self.coalesced_uploads = 0
         self.per_field_uploads = 0
         self.io_retries = 0
         self.rowgroups_quarantined = 0
@@ -113,7 +96,6 @@ class LoaderStats(object):
         self.wire_bytes_copied_per_batch = 0.0
         self.device_decode_batches = 0
         self.device_fallback_batches = 0
-        self.unpack_cache_evictions = 0
 
     def add(self, **deltas):
         """Add keyword deltas to counter fields atomically (one lock hold)."""
@@ -178,20 +160,6 @@ class JaxDataLoader(object):
     :param prefetch: device batches kept in flight (2 = double buffering).
     :param drop_last: drop the final partial batch (keeps shapes static under jit).
     :param device_put: False returns host numpy batches (debugging / CPU consumers).
-    :param coalesce_fields: pack every field of a batch into ONE host buffer and
-        issue ONE host->device transfer per batch, unpacking on device inside a
-        cached jitted program (slice + bitcast — fused view-level work). On a
-        tunneled/high-RTT link each transfer pays a dispatch round trip, so a
-        3-field batch costs 3 RTTs per batch without this (VERDICT r4 item 2:
-        "coalesce device_put across fields"). Default ``None`` = auto: enabled
-        on accelerator backends, disabled on CPU, where ``device_put`` is a
-        near-free buffer share and the on-device unpack would be a pure host
-        memcpy tax (measured ~8x per-batch overhead). Applies on the
-        single-device path (``mesh=None``) when every field has a native-endian
-        numeric dtype; anything else silently uses the per-field path. JAX
-        exposes no user pinned-host-memory control, so a pinned staging buffer
-        is not available to us — the packed buffer is the closest equivalent
-        (one contiguous region, reused layout).
     :param device_transforms: ``{field: DeviceTransform}`` on-device augment
         chains (crop/flip/normalize) for raw-shipped image fields — requires a
         reader built with ``device_decode_fields`` (docs/performance.md
@@ -233,7 +201,7 @@ class JaxDataLoader(object):
     def __init__(self, reader, batch_size, mesh=None, partition_spec=None,
                  shuffling_queue_capacity=0, min_after_retrieve=None, seed=None,
                  pad_ragged=None, prefetch=2, drop_last=True, device_put=True,
-                 coalesce_fields=None, device_transforms=None,
+                 device_transforms=None,
                  device_buffer_depth=2, metrics_port=None, slo_policy=None,
                  incidents=None, history=None):
         if batch_size < 1:
@@ -292,8 +260,6 @@ class JaxDataLoader(object):
         self._lineage_steps = 0
         self._scan_stream_programs = {}
         self._scan_stream_cache_warned = False
-        self._coalesce_fields = coalesce_fields
-        self._unpack_programs = collections.OrderedDict()
         # Device-resident decode tail (docs/performance.md): when the reader
         # ships raw codec payloads, this stage finishes decode (and augment)
         # as jitted device kernels after the upload; on CPU backends it
@@ -650,10 +616,6 @@ class JaxDataLoader(object):
                                  sharding_for_field(sharding, name), col)
                              for name, col in columns.items()}
                     self.stats.add(per_field_uploads=1)
-                elif (self._coalesce_enabled()
-                      and (layout := coalescible_layout(columns)) is not None):
-                    batch = self._put_coalesced(columns, sharding, layout)
-                    self.stats.add(coalesced_uploads=1)
                 else:
                     batch = jax.device_put(columns, sharding)
                     self.stats.add(per_field_uploads=1)
@@ -691,42 +653,6 @@ class JaxDataLoader(object):
         # array's leading dim is the GLOBAL batch, but stats and delivery accounting are
         # per-host.
         self._put((batch, local_rows), out_queue, stop_event)
-
-    def _coalesce_enabled(self):
-        """Resolve the auto default once: coalescing pays on accelerators
-        (fewer link round trips) and costs on CPU (pure memcpy tax)."""
-        if self._coalesce_fields is None:
-            import jax
-            self._coalesce_fields = jax.devices()[0].platform != 'cpu'
-        return self._coalesce_fields
-
-    def _put_coalesced(self, columns, sharding, layout):
-        """ONE H2D transfer for the whole batch: pack every field's bytes into a
-        single uint8 buffer, upload it, and unpack on device through a cached
-        jitted slice+bitcast program (see the ``coalesce_fields`` docstring).
-        ``layout`` is the caller's ``coalescible_layout`` guard result."""
-        import jax
-        names = [name for name, _, _ in layout]
-        parts = [columns[name].view(np.uint8).ravel() for name in names]
-        buf = np.concatenate(parts) if len(parts) > 1 else parts[0]
-        dev_buf = jax.device_put(buf, sharding)
-        # Small LRU: layouts are stable per stream, but a long-lived loader
-        # iterating readers with varying field sets must not grow this without
-        # bound. A hit moves the program to the MRU end; evictions are counted
-        # in LoaderStats so layout churn is observable, never silent.
-        programs = self._unpack_programs
-        x64 = bool(jax.config.jax_enable_x64)
-        key = (layout, x64)
-        program = programs.get(key)
-        if program is None:
-            if len(programs) >= _UNPACK_CACHE_MAX:
-                programs.popitem(last=False)
-                self.stats.add(unpack_cache_evictions=1)
-            program = jax.jit(_make_unpack(layout, x64))
-            programs[key] = program
-        else:
-            programs.move_to_end(key)
-        return program(dev_buf)
 
     def _put(self, item, out_queue, stop_event):
         while not stop_event.is_set():
@@ -856,10 +782,6 @@ class JaxDataLoader(object):
                     chunk = {name: jax.make_array_from_process_local_data(
                                  sharding_for_field(sharding, name), col)
                              for name, col in chunk.items()}
-                elif (self._coalesce_enabled()
-                      and (layout := coalescible_layout(chunk)) is not None):
-                    # one transfer per chunk instead of one per field
-                    chunk = self._put_coalesced(chunk, sharding, layout)
                 else:
                     chunk = jax.device_put(chunk, sharding)
             self.observe_traced('h2d', time.perf_counter() - h2d_start,
@@ -1185,6 +1107,13 @@ class JaxDataLoader(object):
         self.reader.stop()
 
     def join(self):
+        # The producer thread may still be polling the reader's worker pool;
+        # zmq sockets are not thread-safe, so it must be gone before the
+        # pool's join() drains and closes them.
+        producer = self._producer
+        if producer is not None and producer is not threading.current_thread():
+            self._drain_queue()
+            producer.join(timeout=30)
         self.reader.join()
 
     def __enter__(self):
@@ -1303,70 +1232,6 @@ def _chunk_sharding(sharding):
     if isinstance(sharding, NamedSharding):
         return NamedSharding(sharding.mesh, PartitionSpec(None, *sharding.spec))
     return sharding
-
-
-def coalescible_layout(columns):
-    """Layout key for the coalesced single-transfer upload, or None when any
-    field disqualifies the batch: every column must be a C-contiguous ndarray of
-    a native-endian bool/int/uint/float dtype whose device representation the
-    unpack program can reproduce bit- (or canonicalization-) exactly. Under
-    default x32, 64-bit ints canonicalize by mod-2^32 truncation — reproduced
-    on device from the packed bytes' low words — while ``float64``'s rounding
-    conversion cannot be expressed without 64-bit types, so it falls back to
-    the per-field path. The key is a tuple of ``(name, dtype_str, shape)`` —
-    hashable, and identical batches of a stream share one compiled program."""
-    import jax
-    x64 = bool(jax.config.jax_enable_x64)
-    layout = []
-    for name in sorted(columns):
-        col = columns[name]
-        if not isinstance(col, np.ndarray) or col.dtype.kind not in 'biuf':
-            return None
-        if col.dtype.byteorder not in ('=', '|', '<'):
-            return None
-        if col.dtype.itemsize == 8 and col.dtype.kind == 'f' and not x64:
-            return None
-        if not col.flags.c_contiguous:
-            return None
-        layout.append((name, col.dtype.str, col.shape))
-    return tuple(layout) if layout else None
-
-
-def _make_unpack(layout, x64):
-    """Device-side unpack for a packed uint8 buffer: static slices + bitcast per
-    field — view-level ops XLA fuses into the consuming program. Matches
-    ``jax.device_put``'s dtype canonicalization: under x32, int64/uint64
-    columns land as int32/uint32 via mod-2^32 truncation, which for
-    little-endian packed bytes is exactly the low 4-byte word."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    def unpack(buf):
-        out = {}
-        offset = 0
-        for name, dtype_str, shape in layout:
-            dtype = np.dtype(dtype_str)
-            nbytes = int(np.prod(shape, dtype=np.int64)) * dtype.itemsize
-            seg = buf[offset:offset + nbytes]
-            offset += nbytes
-            if dtype == np.uint8:
-                arr = seg
-            elif dtype == np.bool_:
-                arr = seg != 0
-            elif dtype.itemsize == 1:
-                arr = lax.bitcast_convert_type(seg, jnp.dtype(dtype))
-            elif dtype.itemsize == 8 and dtype.kind in 'iu' and not x64:
-                words = lax.bitcast_convert_type(seg.reshape(-1, 4), jnp.uint32)
-                low = words.reshape(-1, 2)[:, 0]  # little-endian low word
-                target = jnp.int32 if dtype.kind == 'i' else jnp.uint32
-                arr = lax.bitcast_convert_type(low, target)
-            else:
-                arr = lax.bitcast_convert_type(
-                    seg.reshape(-1, dtype.itemsize), jnp.dtype(dtype))
-            out[name] = arr.reshape(shape)
-        return out
-
-    return unpack
 
 
 def sanitize_columns(columns, pad_ragged, device_put, passthrough=frozenset()):

@@ -12,21 +12,6 @@ import os
 import numpy as np
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs, check=False):
-    """``jax.shard_map`` across jax versions (the kwarg was renamed check_rep ->
-    check_vma in 0.8, and the function moved out of jax.experimental)."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_vma=check)
-    except TypeError:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=check)
-
-
 def make_mesh(axis_names=('data',), axis_sizes=None, devices=None):
     """Build a :class:`jax.sharding.Mesh` over the available devices.
 

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from petastorm_tpu.parallel.mesh import shard_map_compat
-
 
 def stack_stage_params(stage_params_list):
     """Stack a list of per-stage parameter pytrees into one pytree whose leaves carry
@@ -128,7 +126,8 @@ def make_pipeline(stage_fn, mesh, stage_axis='stage', xs_spec=P(), out_spec=P(),
         return lax.psum(jnp.where(is_last, outputs, jnp.zeros_like(outputs)),
                         stage_axis)
 
-    return shard_map_compat(local_fn, mesh, (params_spec, xs_spec), out_spec)
+    return jax.shard_map(local_fn, mesh=mesh, in_specs=(params_spec, xs_spec),
+                         out_specs=out_spec, check_vma=False)
 
 
 def microbatch(batch, n_micro):

@@ -198,7 +198,7 @@ def make_reader(dataset_url_or_urls, schema_fields=None,
     ``DctImageCodec``, raw ``.npy`` bytes for ``NdarrayCodec``, raw deflate
     frames for ``CompressedNdarrayCodec``) and the
     :class:`~petastorm_tpu.parallel.loader.JaxDataLoader` decodes them as
-    jitted device kernels after ONE coalesced upload, double-buffered against
+    jitted device kernels after the upload, double-buffered against
     the train step. Raw-form values reach non-loader consumers as-is; the
     small ``__hw``/``__enc`` auxiliary metadata columns ride
     ``iter_columnar`` batches only (the namedtuple row/batch APIs emit schema

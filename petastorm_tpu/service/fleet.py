@@ -199,6 +199,8 @@ class ServiceFleet(object):
             existing = env.get('PYTHONPATH')
             env['PYTHONPATH'] = os.pathsep.join(
                 parent_paths + ([existing] if existing else []))
+            # workers are host-side: never contend for the trainer's chip
+            env['JAX_PLATFORMS'] = 'cpu'
             # after a successful spawn the WORKER owns the bootstrap file
             # (service_worker.main unlinks it right after loading)
             process = subprocess.Popen(

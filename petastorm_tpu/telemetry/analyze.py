@@ -87,9 +87,8 @@ _KNOBS: Dict[str, Any] = {
                 'Host batch assembly (sanitize/pad) dominates: bigger '
                 'batch_size amortizes per-batch cost; pad_ragged fields copy '
                 'every row — pack or pre-pad in the store.'),
-    'h2d': ('coalesce uploads / raise batch size (link-bound)',
-            'Host->device transfer dominates: coalesce_fields=True collapses '
-            'per-field transfers to one; a larger batch_size amortizes '
+    'h2d': ('raise batch size (link-bound)',
+            'Host->device transfer dominates: a larger batch_size amortizes '
             'per-transfer dispatch RTT; scan_stream uploads whole chunks.'),
     'cache_miss': ('first-epoch fills — see rowgroup_read/decode',
                    'cache_miss envelopes the fill work; the leaf ranking names '

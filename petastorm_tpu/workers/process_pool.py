@@ -294,6 +294,9 @@ class ProcessPool(object):
         existing = self._child_env.get('PYTHONPATH')
         self._child_env['PYTHONPATH'] = os.pathsep.join(
             parent_paths + ([existing] if existing else []))
+        # The trainer process holds the chip; a worker that imported jax (a
+        # user transform may) must never try to take it too.
+        self._child_env['JAX_PLATFORMS'] = 'cpu'
         # Propagate the telemetry kill switch: set_telemetry_enabled(False) in
         # the parent must also silence SPAWNED workers (captured at pool start;
         # an explicit PETASTORM_TPU_TELEMETRY in the env wins).
@@ -736,12 +739,15 @@ class ProcessPool(object):
                 # Hang detection belongs exactly here: the queues are drained and
                 # the consumer is genuinely starved, so heartbeat staleness and
                 # item deadlines measure the workers, not a busy consumer.
-                if not self._stopped:
-                    self._check_hangs()
-                    if self._hang_results:
-                        # a reap just quarantined item(s) — deliver the stand-in
-                        # BEFORE the completed() check can end the epoch
-                        continue
+                if self._stopped:
+                    # stop() came from another thread: return to the caller
+                    # before that thread's join() polls these same sockets
+                    raise RuntimeError('the worker pool was stopped')
+                self._check_hangs()
+                if self._hang_results:
+                    # a reap just quarantined item(s) — deliver the stand-in
+                    # BEFORE the completed() check can end the epoch
+                    continue
                 if self._ventilator is not None and getattr(self._ventilator, 'error', None):
                     self.stop()
                     raise self._ventilator.error

@@ -128,7 +128,6 @@ def run_compose4(n):
     from petastorm_tpu.ops.ring_attention import ring_attention
     from petastorm_tpu.parallel import (make_pipeline, microbatch,
                                         stack_stage_params, unstack_stage_params)
-    from petastorm_tpu.parallel.mesh import shard_map_compat
 
     data, seq, stage, model = {16: (2, 2, 2, 2), 32: (2, 2, 4, 2)}[n]
     mesh = Mesh(np.asarray(jax.devices()[:n]).reshape(data, seq, stage, model),
@@ -151,9 +150,9 @@ def run_compose4(n):
         is_leaf=lambda x: isinstance(x, P))
 
     qkv_spec = P('data', 'seq', None, None)
-    sp_attn = shard_map_compat(
+    sp_attn = jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, axis_name='seq', causal=True),
-        mesh, (qkv_spec, qkv_spec, qkv_spec), qkv_spec)
+        mesh=mesh, in_specs=(qkv_spec, qkv_spec, qkv_spec), out_specs=qkv_spec, check_vma=False)
 
     def tp_stage_fn(p, mb):
         h = jax.nn.gelu(mb @ p['w1'])
@@ -188,7 +187,6 @@ def run_wide3(n):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from petastorm_tpu.ops.ring_attention import ring_attention
-    from petastorm_tpu.parallel.mesh import shard_map_compat
 
     data, seq, model = {32: (2, 4, 4)}[n]
     mesh = Mesh(np.asarray(jax.devices()[:n]).reshape(data, seq, model),
@@ -211,9 +209,10 @@ def run_wide3(n):
         return e + jax.lax.psum(h @ w2, 'model')
 
     x_spec = P('data', 'seq', None, None)
-    block = shard_map_compat(
-        block_local, mesh,
-        (x_spec, P(None, 'model'), P('model', None)), P('data', 'seq', None))
+    block = jax.shard_map(
+        block_local, mesh=mesh,
+        in_specs=(x_spec, P(None, 'model'), P('model', None)),
+        out_specs=P('data', 'seq', None), check_vma=False)
 
     def loss_sharded(params, tokens, labels):
         x = params['embed'][tokens].reshape(tokens.shape[0], tokens.shape[1], H, D)
@@ -247,7 +246,6 @@ def run_compose4_expert(n):
     from petastorm_tpu.ops.sharded_moe import sharded_moe_ffn
     from petastorm_tpu.parallel import (make_pipeline, microbatch,
                                         stack_stage_params, unstack_stage_params)
-    from petastorm_tpu.parallel.mesh import shard_map_compat
 
     data, seq, stage, expert = {16: (2, 2, 2, 2), 32: (2, 2, 4, 2)}[n]
     mesh = Mesh(np.asarray(jax.devices()[:n]).reshape(data, seq, stage, expert),
@@ -274,9 +272,9 @@ def run_compose4_expert(n):
         is_leaf=lambda x: isinstance(x, P))
 
     qkv_spec = P('data', 'seq', None, None)
-    sp_attn = shard_map_compat(
+    sp_attn = jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, axis_name='seq', causal=True),
-        mesh, (qkv_spec, qkv_spec, qkv_spec), qkv_spec)
+        mesh=mesh, in_specs=(qkv_spec, qkv_spec, qkv_spec), out_specs=qkv_spec, check_vma=False)
 
     def moe_stage_fn(p, mb):
         flat = mb.reshape(-1, E)

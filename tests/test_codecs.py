@@ -86,6 +86,39 @@ class TestNdarrayCodecs:
         with pytest.raises(ValueError, match='shape'):
             codec.encode(field, np.zeros((4,), dtype=np.float32))
 
+    @pytest.mark.parametrize('level', [None, 0, 1, 9])
+    def test_compresslevel_roundtrip(self, level):
+        from petastorm_tpu.codecs import codec_from_config
+        codec = CompressedNdarrayCodec(compresslevel=level)
+        field = UnischemaField('m', np.float32, (8, 16), codec, False)
+        value = np.random.rand(8, 16).astype(np.float32)
+        np.testing.assert_array_equal(_roundtrip(codec, field, value), value)
+        np.testing.assert_array_equal(codec.decode(field, codec.encode(field, value)), value)
+        assert codec_from_config(codec.to_config()) == codec
+
+    def test_default_level_config_unchanged(self):
+        # stores written before the option existed keep their schema config
+        assert CompressedNdarrayCodec().to_config() == {'codec': 'compressed_ndarray'}
+        assert CompressedNdarrayCodec(0) != CompressedNdarrayCodec()
+
+    @pytest.mark.parametrize('level', [-1, 10])
+    def test_compresslevel_out_of_range(self, level):
+        with pytest.raises(ValueError, match='compresslevel'):
+            CompressedNdarrayCodec(compresslevel=level)
+
+    @pytest.mark.parametrize('level,stored', [(0, True), (None, False)])
+    def test_level_zero_frames_are_device_inflatable(self, level, stored):
+        """Level 0 leaves every deflate block stored, the frames the device
+        inflates; zlib's default level opens with a Huffman block."""
+        from petastorm_tpu.codecs import _npz_raw_member
+        from petastorm_tpu.ops.raw_decode import parse_stored_deflate_layout
+        codec = CompressedNdarrayCodec(compresslevel=level)
+        field = UnischemaField('m', np.float32, (256,), codec, False)
+        blob = codec.encode(field, np.random.rand(256).astype(np.float32))
+        method, body = _npz_raw_member(blob)
+        assert method == 8
+        assert (parse_stored_deflate_layout(bytes(body)) is not None) == stored
+
     def test_compressed_smaller_on_redundant_data(self):
         field_plain = UnischemaField('m', np.float32, (100, 100), NdarrayCodec(), False)
         value = np.zeros((100, 100), dtype=np.float32)

@@ -1,9 +1,8 @@
 """Device-resident decode tail tests (ISSUE 10): ship-raw decode plans, the
-ops/raw_decode kernels (npy bitcast unpack + stored-block deflate Pallas copy),
+ops/raw_decode kernels (npy bitcast unpack + stored-block deflate gather),
 CPU-fallback byte-parity through the JaxDataLoader (images + compressed
 ndarrays, ragged and null cells included), the disarmed-mode no-change
-contract, device transforms, the autotune knob surface, and the coalesced
-unpack-program LRU."""
+contract, device transforms and the autotune knob surface."""
 
 import os
 import zlib
@@ -416,13 +415,12 @@ def test_forced_device_mode_decodes_on_device(tmp_path, monkeypatch):
         assert diff.max() <= 1  # XLA vs numpy float rounding at the clip edge
 
 
-def test_forced_device_mode_coalesced_single_transfer(tmp_path, monkeypatch):
+def test_forced_device_mode_uploads_field_by_field(tmp_path, monkeypatch):
     monkeypatch.setenv('PETASTORM_TPU_DEVICE_DECODE_FORCE', '1')
     url = _write_store(tmp_path)
-    _, stats, _ = _loader_batches(url, ['img', 'vec', 'mat'],
-                                  coalesce_fields=True)
-    assert stats['coalesced_uploads'] > 0
-    assert stats['device_decode_batches'] > 0
+    _, stats, _ = _loader_batches(url, ['img', 'vec', 'mat'])
+    assert stats['per_field_uploads'] == stats['batches'] > 0
+    assert stats['device_decode_batches'] == stats['batches']
 
 
 def test_device_transform_crop_flip_normalize(tmp_path, monkeypatch):
@@ -623,39 +621,3 @@ def test_set_prefetch_moves_live_queue(tmp_path):
         for _ in it:
             pass
     assert loader.set_device_buffer_depth(7) == 7  # clamp-only, no stage
-
-
-def test_unpack_program_cache_is_lru_with_eviction_counter():
-    """Satellite: the coalesced-upload unpack-program cache is a bounded LRU
-    whose evictions are counted — a hit refreshes recency, so a hot layout
-    survives a parade of one-shot layouts."""
-    import jax
-    from petastorm_tpu.parallel import loader as loader_mod
-
-    class _FakeReader:
-        device_decode_fields = frozenset()
-
-    ldr = loader_mod.JaxDataLoader.__new__(loader_mod.JaxDataLoader)
-    ldr.stats = loader_mod.LoaderStats()
-    ldr._unpack_programs = __import__('collections').OrderedDict()
-    sharding = loader_mod.resolve_sharding(None, None, True)
-
-    def put(columns):
-        layout = loader_mod.coalescible_layout(columns)
-        assert layout is not None
-        return ldr._put_coalesced(columns, sharding, layout)
-
-    hot = {'a': np.arange(8, dtype=np.float32)}
-    put(hot)
-    for i in range(loader_mod._UNPACK_CACHE_MAX - 1):
-        put({'b': np.arange(3 + i, dtype=np.int32)})
-    assert ldr.stats.as_dict()['unpack_cache_evictions'] == 0
-    put(hot)  # refresh recency of the hot layout
-    put({'c': np.arange(40, dtype=np.int8)})  # evicts the LRU, not the hot one
-    stats = ldr.stats.as_dict()
-    assert stats['unpack_cache_evictions'] == 1
-    x64 = bool(jax.config.jax_enable_x64)
-    hot_key = (loader_mod.coalescible_layout(hot), x64)
-    assert hot_key in ldr._unpack_programs
-    out = np.asarray(put(hot)['a'])
-    np.testing.assert_array_equal(out, hot['a'])

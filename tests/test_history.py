@@ -5,8 +5,8 @@ trailing median/MAD compare + change-point attribution engine with its
 exit-coded CLI, the live regression sentinel (Page-Hinkley drift matrix:
 step drop fires exactly once, slow drift fires, noisy stationary never
 false-positives) wired into the incident plane, and the satellites
-(SloTracker ring-buffer history, autotune warm start, bench trailing-median
-baseline)."""
+(SloTracker ring-buffer history, autotune warm start, bench section
+registration)."""
 import importlib.util
 import json
 import os
@@ -713,7 +713,7 @@ class TestDispatcherHistoryWiring:
 
 
 # ---------------------------------------------------------------------------
-# satellites: SLO ring buffer, bench trailing baseline
+# satellites: SLO ring buffer, bench section registration
 # ---------------------------------------------------------------------------
 
 class TestSloHistoryRingBuffer:
@@ -749,7 +749,7 @@ class TestSloHistoryRingBuffer:
             assert snapshot['slo_history'] == report['history']
 
 
-class TestBenchTrailingBaseline:
+class TestBenchHistorySection:
     def _load_bench(self):
         spec = importlib.util.spec_from_file_location(
             'bench_module_history',
@@ -757,38 +757,6 @@ class TestBenchTrailingBaseline:
         module = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(module)
         return module
-
-    def test_trailing_median_absorbs_one_outlier_round(self, tmp_path):
-        bench = self._load_bench()
-        rounds = [
-            {'parsed': {'platform': 'cpu', 'streaming_rows_per_sec': 100.0}},
-            {'parsed': {'platform': 'cpu', 'streaming_rows_per_sec': 20.0}},
-            {'parsed': {'platform': 'cpu', 'streaming_rows_per_sec': 104.0}},
-        ]
-        for i, payload in enumerate(rounds):
-            path = tmp_path / 'BENCH_r{:02d}.json'.format(i + 1)
-            path.write_text(json.dumps(payload))
-            os.utime(str(path), (i + 1, i + 1))
-        paths = bench.trailing_bench_baselines(str(tmp_path), window=3)
-        baseline, used = bench.trailing_median_baseline(
-            {'platform': 'cpu'}, paths)
-        assert len(used) == 3
-        # the r02 outlier round cannot drag the reference down to 20
-        assert baseline['streaming_rows_per_sec'] == 100.0
-        regressions = bench.compare_to_baseline(
-            {'platform': 'cpu', 'streaming_rows_per_sec': 50.0}, baseline)
-        assert regressions[0]['drop_pct'] == 50.0
-
-    def test_cross_platform_rounds_compare_to_nothing(self, tmp_path):
-        bench = self._load_bench()
-        path = tmp_path / 'BENCH_r01.json'
-        path.write_text(json.dumps(
-            {'parsed': {'platform': 'tpu',
-                        'streaming_rows_per_sec': 5000.0}}))
-        baseline, used = bench.trailing_median_baseline(
-            {'platform': 'cpu'},
-            bench.trailing_bench_baselines(str(tmp_path)))
-        assert baseline is None and used == []
 
     def test_history_section_registered(self):
         bench = self._load_bench()

@@ -397,8 +397,17 @@ def test_doctor_reports_retained_incidents(tmp_path, monkeypatch):
 # end-to-end acceptance (a): hang reaped mid-epoch -> hang bundle, exit 10
 # ---------------------------------------------------------------------------
 
+@pytest.fixture
+def disarm_tracing():
+    """``make_reader(trace=True)`` arms the process-global recorder: disarm it
+    after the test so later tests in this worker start with it off."""
+    yield
+    tracing.set_trace_enabled(False)
+    tracing.reset_tracing()
+
+
 @pytest.mark.faultinject
-def test_e2e_hang_reap_one_bundle_ctx_in_trace_autopsy_hang(tmp_path):
+def test_e2e_hang_reap_one_bundle_ctx_in_trace_autopsy_hang(tmp_path, disarm_tracing):
     url = _write_store(tmp_path / 'store', num_rows=64, n_files=8)
     import glob as globmod
     parts = sorted(globmod.glob(os.path.join(str(tmp_path / 'store'), '**',
@@ -627,10 +636,10 @@ def test_reader_scrape_never_renders_warmup_efficiency_zero(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# satellite: bench baseline comparison (pure-function diff over two files)
+# satellite: bench section registration
 # ---------------------------------------------------------------------------
 
-class TestBenchBaselineComparison:
+class TestBenchIncidentsSection:
     def _load_bench(self):
         import importlib.util
         spec = importlib.util.spec_from_file_location(
@@ -639,34 +648,6 @@ class TestBenchBaselineComparison:
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
         return mod
-
-    def test_compare_two_synthetic_bench_files(self, tmp_path):
-        bench = self._load_bench()
-        old = {'n': 4, 'rc': 0, 'parsed': {
-            'platform': 'cpu', 'streaming_rows_per_sec': 100.0,
-            'lineage_armed_rows_per_sec': 50.0, 'schedule_speedup': 2.0,
-            'incidents_overhead_pct': 1.0, 'failed_rows_per_sec': 0.0}}
-        new = {'platform': 'cpu', 'streaming_rows_per_sec': 80.0,
-               'lineage_armed_rows_per_sec': 49.0, 'schedule_speedup': 2.5,
-               'incidents_overhead_pct': 9.0, 'failed_rows_per_sec': 10.0}
-        (tmp_path / 'BENCH_r01.json').write_text(json.dumps(old))
-        newer = tmp_path / 'BENCH_r02.json'
-        newer.write_text(json.dumps(
-            {'parsed': dict(old['parsed'], streaming_rows_per_sec=95.0)}))
-        os.utime(str(tmp_path / 'BENCH_r01.json'), (1, 1))
-        # newest file wins (mtime order)
-        assert bench.newest_bench_baseline(str(tmp_path)) == str(newer)
-        regressions = bench.compare_to_baseline(new, old)
-        # only the >10% rate drop is flagged: the -2% drift, the improved
-        # speedup, the non-rate overhead key and the zero-valued old key
-        # are all ignored
-        assert regressions == [{'key': 'streaming_rows_per_sec',
-                                'old': 100.0, 'new': 80.0,
-                                'drop_pct': 20.0}]
-        # platform mismatch compares to nothing (CPU fallback vs TPU round)
-        assert bench.compare_to_baseline(dict(new, platform='tpu'),
-                                         old) == []
-        assert bench.compare_to_baseline(new, {'parsed': None}) == []
 
     def test_incidents_section_registered(self):
         bench = self._load_bench()

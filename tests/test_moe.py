@@ -137,7 +137,7 @@ class TestMoEExpertParallel(object):
                                  is_leaf=lambda l: isinstance(l, P))
         sharded_params = jax.device_put(params, shardings)
         x_sharded = jax.device_put(x, NamedSharding(mesh, P('data', None, None)))
-        with mesh:
+        with jax.set_mesh(mesh):
             fn = jax.jit(lambda p, x: model.apply(p, x, mutable='losses')[0])
             y = fn(sharded_params, x_sharded)
         np.testing.assert_allclose(np.asarray(y), np.asarray(unsharded),
@@ -183,7 +183,7 @@ class TestMoEExpertParallel(object):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             return optax.apply_updates(params, updates), opt_state, loss
 
-        with mesh:
+        with jax.set_mesh(mesh):
             losses = []
             for _ in range(8):
                 params, opt_state, loss = step(params, opt_state, tokens)

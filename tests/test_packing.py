@@ -135,16 +135,16 @@ class TestPackedRingAttention(object):
         from jax.sharding import Mesh, PartitionSpec as P
 
         from petastorm_tpu.ops.ring_attention import ring_attention
-        from petastorm_tpu.parallel.mesh import shard_map_compat
 
         rng = np.random.RandomState(7)
         q, k, v = (jnp.asarray(rng.randn(2, 16, 2, 4), jnp.float32)
                    for _ in range(3))
         mesh = Mesh(np.asarray(jax.devices()[:4]), ('seq',))
         qkv_spec = P(None, 'seq', None, None)
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             lambda q, k, v: ring_attention(q, k, v, axis_name='seq', causal=True),
-            mesh, (qkv_spec, qkv_spec, qkv_spec), qkv_spec)
+            mesh=mesh, in_specs=(qkv_spec, qkv_spec, qkv_spec), out_specs=qkv_spec,
+            check_vma=False)
         np.testing.assert_allclose(np.asarray(jax.jit(fn)(q, k, v)),
                                    np.asarray(dense_attention(q, k, v, causal=True)),
                                    rtol=2e-5, atol=2e-6)

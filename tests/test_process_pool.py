@@ -58,6 +58,38 @@ def test_worker_hard_kill_raises_when_respawn_disabled(synthetic_dataset):
             pytest.fail('reader kept serving for 30s with a killed worker')
 
 
+def test_get_results_returns_when_stopped_from_another_thread(synthetic_dataset):
+    """A consumer thread blocked in get_results() leaves it once stop() is called
+    elsewhere, so that thread can be joined before the pool's join() polls and
+    closes the same (not thread-safe) zmq sockets."""
+    import threading
+
+    from petastorm_tpu.workers.process_pool import ProcessPool
+
+    pool = ProcessPool(2)
+    reader = make_reader(synthetic_dataset.url, reader_pool=pool, schema_fields=['id'],
+                         num_epochs=None)
+    next(reader)
+    errors = []
+
+    def consume():
+        try:
+            while True:
+                pool.get_results()
+        except RuntimeError as exc:
+            errors.append(exc)
+
+    consumer = threading.Thread(target=consume, daemon=True)
+    consumer.start()
+    reader.stop()
+    consumer.join(timeout=15)
+    alive = consumer.is_alive()
+    if not alive:
+        reader.join()
+    assert not alive, 'get_results kept polling a stopped pool'
+    assert 'stopped' in str(errors[0])
+
+
 @pytest.mark.slow
 def test_worker_hard_kill_respawns_and_completes(synthetic_dataset):
     """Default pool: a killed worker is respawned within the budget, its in-flight

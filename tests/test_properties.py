@@ -270,54 +270,3 @@ class TestNgramResumeProperty:
             return
         rest, _ = self._read(url, resume_state=state)
         assert first + rest == baseline, 'cut at {}'.format(cut)
-
-
-class TestCoalescedUnpackProperties:
-    """For ANY batch of native numeric columns, the packed-buffer device unpack
-    (loader.coalescible_layout + _make_unpack) reproduces jax.device_put's
-    per-field result bit-for-bit — including x32 canonicalization of 64-bit
-    ints (mod-2^32 truncation) and bool round-trips."""
-
-    _DTYPES = [np.uint8, np.int8, np.bool_, np.int16, np.uint16, np.int32,
-               np.uint32, np.float16, np.float32, np.int64, np.uint64]
-
-    @given(st.data())
-    @settings(max_examples=25, deadline=None)
-    def test_unpack_matches_per_field_device_put(self, data):
-        import jax
-        from petastorm_tpu.parallel.loader import (_make_unpack,
-                                                   coalescible_layout)
-        n_fields = data.draw(st.integers(1, 4))
-        rows = data.draw(st.integers(1, 5))
-        columns = {}
-        for i in range(n_fields):
-            dtype = np.dtype(data.draw(st.sampled_from(self._DTYPES)))
-            extra = tuple(data.draw(
-                st.lists(st.integers(1, 4), min_size=0, max_size=2)))
-            shape = (rows,) + extra
-            n = int(np.prod(shape))
-            if dtype == np.bool_:
-                values = np.array(
-                    data.draw(st.lists(st.booleans(), min_size=n, max_size=n)),
-                    dtype)
-            elif dtype.kind == 'f':
-                values = np.array(data.draw(st.lists(
-                    st.floats(-1e4, 1e4, width=32), min_size=n, max_size=n)),
-                    dtype)
-            else:
-                info = np.iinfo(dtype)
-                values = np.array(data.draw(st.lists(
-                    st.integers(int(info.min), int(info.max)),
-                    min_size=n, max_size=n)), dtype)
-            columns['f{}'.format(i)] = values.reshape(shape)
-        layout = coalescible_layout(columns)
-        assert layout is not None
-        buf = np.concatenate(
-            [columns[name].view(np.uint8).ravel() for name, _, _ in layout])
-        unpacked = jax.jit(_make_unpack(
-            layout, bool(jax.config.jax_enable_x64)))(jax.device_put(buf))
-        for name, col in columns.items():
-            want = jax.device_put(col)
-            assert unpacked[name].dtype == want.dtype, name
-            np.testing.assert_array_equal(np.asarray(unpacked[name]),
-                                          np.asarray(want), err_msg=name)

@@ -9,7 +9,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from petastorm_tpu.models.moe import _capacity, switch_routing
 from petastorm_tpu.ops.sharded_moe import expert_alltoall_ffn, sharded_moe_ffn
-from petastorm_tpu.parallel.mesh import shard_map_compat
 
 N_EXPERTS = 8
 DIM = 16
@@ -53,14 +52,14 @@ def mesh_2x4():
 
 
 def sharded_fn(mesh, capacity_factor=8.0, num_selected=1):
-    return shard_map_compat(
+    return jax.shard_map(
         lambda t, rk, w1, w2: sharded_moe_ffn(
             t, rk, w1, w2, 'expert', capacity_factor=capacity_factor,
             num_selected=num_selected)[0],
-        mesh,
-        (P('data', None), P(None, None), P('expert', None, None),
+        mesh=mesh,
+        in_specs=(P('data', None), P(None, None), P('expert', None, None),
          P('expert', None, None)),
-        P('data', None))
+        out_specs=P('data', None), check_vma=False)
 
 
 class TestShardedMoE(object):
@@ -128,10 +127,10 @@ class TestShardedMoE(object):
             return (tokens + out).reshape(attn.shape)
 
         x_spec = P('data', 'seq', None, None)
-        fn = shard_map_compat(
-            layer, mesh,
-            (x_spec, P(None, None), P('expert', None, None),
-             P('expert', None, None)), x_spec)
+        fn = jax.shard_map(
+            layer, mesh=mesh,
+            in_specs=(x_spec, P(None, None), P('expert', None, None),
+             P('expert', None, None)), out_specs=x_spec, check_vma=False)
         got = jax.jit(fn)(x, router_kernel, w1, w2)
 
         # Reference: dense attention, then per-(data, seq)-shard routing + FFN on
@@ -157,11 +156,11 @@ class TestShardedMoE(object):
         w1 = jnp.asarray(rng.randn(6, DIM, HID), jnp.float32)
         w2 = jnp.asarray(rng.randn(6, HID, DIM), jnp.float32)
         dispatch = jnp.zeros((16, 6, 4), jnp.float32)
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             lambda t, d, w1, w2: expert_alltoall_ffn(t, d, d, w1, w2, 'expert'),
-            mesh, (P('data', None), P('data', None, None),
+            mesh=mesh, in_specs=(P('data', None), P('data', None, None),
                    P(None, None, None), P(None, None, None)),
-            P('data', None))
+            out_specs=P('data', None), check_vma=False)
         with pytest.raises(ValueError):
             jax.jit(fn)(tokens, dispatch, w1, w2)
 
@@ -174,10 +173,10 @@ class TestShardedMoE(object):
         # replicated in_spec leaves leading dim 8 != 8/4 local experts.
         w1 = jnp.asarray(rng.randn(N_EXPERTS, DIM, HID), jnp.float32)
         w2 = jnp.asarray(rng.randn(N_EXPERTS, HID, DIM), jnp.float32)
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             lambda t, d, w1, w2: expert_alltoall_ffn(t, d, d, w1, w2, 'expert'),
-            mesh, (P('data', None), P('data', None, None),
+            mesh=mesh, in_specs=(P('data', None), P('data', None, None),
                    P(None, None, None), P(None, None, None)),
-            P('data', None))
+            out_specs=P('data', None), check_vma=False)
         with pytest.raises(ValueError):
             jax.jit(fn)(tokens, dispatch, w1, w2)

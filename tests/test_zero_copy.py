@@ -340,7 +340,6 @@ def test_shm_transport_survives_worker_kill_no_segment_leak(tmp_path):
     from petastorm_tpu.test_util.fault_injection import (FaultRule, FaultSchedule,
                                                          fault_injecting_filesystem)
 
-    before = set(_shm_segments())
     url = _write_store(tmp_path / 'store', num_rows=64, n_files=8)
     target = os.path.basename(sorted(glob.glob(
         os.path.join(str(tmp_path / 'store'), '**', '*.parquet'),
@@ -352,10 +351,12 @@ def test_shm_transport_survives_worker_kill_no_segment_leak(tmp_path):
                      filesystem=fault_injecting_filesystem(sched)) as reader:
         ids = sorted(int(row.id) for row in reader)
         diag = reader.diagnostics
+        ring = reader._pool._ring.name
     assert ids == list(range(64)), 'rows dropped or duplicated across the respawn'
     assert diag['workers_respawned'] == 1
     assert diag['shm_enabled'] and diag['shm_batches'] > 0
-    assert set(_shm_segments()) <= before, 'leaked /dev/shm segment after join()'
+    # this pool's segment only: tests in other xdist workers hold rings of their own
+    assert ring not in _shm_segments(), 'leaked /dev/shm segment after join()'
 
 
 @pytest.mark.slow
