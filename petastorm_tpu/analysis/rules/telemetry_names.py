@@ -40,10 +40,8 @@ from petastorm_tpu.analysis.core import (AnalysisContext, Finding, Rule,
                                          SourceModule, extract_string_tuple,
                                          literal_str_values)
 
-#: call forms checked against STAGES (observe_traced is the loader's
-#: histogram+timeline dual-emission helper)
-_NAME_FUNCS = ('stage_span', 'record_stage', 'trace_complete',
-               'observe_traced')
+#: call forms checked against STAGES
+_NAME_FUNCS = ('stage_span', 'record_stage', 'trace_complete')
 #: call form checked against TRACE_INSTANTS (flight-recorder anomaly markers)
 _INSTANT_FUNCS = ('trace_instant',)
 #: call forms checked against KNOB_IDS: Knob construction and catalog lookup

@@ -36,7 +36,6 @@ from __future__ import annotations
 import collections
 import logging
 import os
-import time
 import zlib
 from dataclasses import dataclass
 from io import BytesIO
@@ -465,7 +464,9 @@ class DeviceDecodeStage:
             entries = [(p.name, p.transform) for p in self._plans.values()
                        if p.transform is not None]
 
-            def run(dev: Dict[str, Any], counter: Any) -> Dict[str, Any]:
+            # named, so the device trace names its ops after it
+            def device_transform(dev: Dict[str, Any],
+                                 counter: Any) -> Dict[str, Any]:
                 out = dict(dev)
                 for name, transform in entries:
                     rng = None
@@ -475,7 +476,7 @@ class DeviceDecodeStage:
                     out[name] = transform.apply(dev[name], rng)
                 return out
 
-            program = jax.jit(run)
+            program = jax.jit(device_transform)
             self._transform_program = program
         counter = np.uint32(self._rng_counter)
         self._rng_counter += 1
@@ -489,7 +490,8 @@ class DeviceDecodeStage:
         from petastorm_tpu.ops.raw_decode import bitcast_rows, stored_inflate
         x64 = self._x64
 
-        def run(dev: Dict[str, Any]) -> Dict[str, Any]:
+        # named, so the device trace names its ops after it
+        def device_decode_finish(dev: Dict[str, Any]) -> Dict[str, Any]:
             out = {name: col for name, col in dev.items()
                    if name != _RNG_NAME and not name.endswith(_SEGS_SUFFIX)}
             counter = dev.get(_RNG_NAME)
@@ -523,24 +525,19 @@ class DeviceDecodeStage:
                                              dtype_str, row_shape, x64=x64)
             return out
 
-        return jax.jit(run)
+        return jax.jit(device_decode_finish)
 
     # ----------------------------------------------------------------- ring
 
-    def throttle(self, batch: Any) -> float:
+    def throttle(self, batch: Any) -> None:
         """Bound dispatched-ahead decode work: append this batch to the ring
         and, past the configured depth, block until the OLDEST dispatched
-        batch is ready. Returns the seconds spent blocked (the loader reports
-        them as the ``d2d_wait`` stage)."""
+        batch is ready (the loader times the call as the ``d2d_wait``
+        stage)."""
         import jax
         leaves = jax.tree_util.tree_leaves(batch)
         if not leaves:
-            return 0.0
+            return
         self._ring.append(leaves[0])
-        waited = 0.0
         while len(self._ring) > self._depth:
-            oldest = self._ring.popleft()
-            start = time.perf_counter()
-            jax.block_until_ready(oldest)
-            waited += time.perf_counter() - start
-        return waited
+            jax.block_until_ready(self._ring.popleft())

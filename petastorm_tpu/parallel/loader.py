@@ -24,22 +24,14 @@ import time
 import warnings
 
 import numpy as np
-from jax.profiler import TraceAnnotation
 
-from petastorm_tpu.telemetry import tracing as _flight
 from petastorm_tpu.parallel.shuffling_buffer import (NoopShufflingBuffer,
                                                      RandomShufflingBuffer)
+from petastorm_tpu.telemetry.spans import stage_span
 
 _END = object()
 #: scan_stream keeps this many compiled (step_fn, chunk-shape) programs per loader
 _SCAN_STREAM_CACHE_MAX = 8
-
-
-def _trace_span(name):
-    """jax.profiler annotation so loader stages show up in device traces next to the
-    XLA ops they feed (SURVEY.md §5.1: the TPU-native replacement for the reference's
-    per-thread cProfile)."""
-    return TraceAnnotation(name)
 
 
 class LoaderStats(object):
@@ -209,10 +201,13 @@ class JaxDataLoader(object):
         self.reader = reader
         self.batch_size = batch_size
         self.stats = LoaderStats()
-        # Loader-side stage telemetry (docs/observability.md): shuffle_wait /
-        # collate / h2d histograms; telemetry_snapshot() merges in the reader's
-        # cross-process view. PETASTORM_TPU_TELEMETRY_JSONL streams periodic
-        # snapshots from the consumer loop.
+        # Loader-side stage telemetry (docs/observability.md): the stage spans
+        # of the consumer (shuffle_wait) and of the producer thread (rebatch /
+        # collate / device_decode / h2d / d2d_wait / put_wait), which also
+        # land on the jax profiler's clock while a profile is taken;
+        # telemetry_snapshot() merges in the reader's cross-process view.
+        # PETASTORM_TPU_TELEMETRY_JSONL streams periodic snapshots from the
+        # consumer loop.
         from petastorm_tpu.telemetry import MetricsRegistry
         from petastorm_tpu.telemetry.export import logger_from_env
         self.telemetry = MetricsRegistry()
@@ -411,25 +406,22 @@ class JaxDataLoader(object):
                                           name='petastorm-tpu-loader-producer')
         self._producer.start()
         try:
-            last_emit = time.monotonic()
+            last_emit = time.perf_counter()
             while True:
-                wait_start = time.monotonic()
-                with _trace_span('petastorm_tpu.loader.wait_input'):
+                # shuffle_wait: time the training loop sat blocked on the input
+                # pipeline for this batch — the stage the stall fraction sums
+                with stage_span('shuffle_wait', registry=self.telemetry) as wait:
                     item = self._queue.get()
-                now = time.monotonic()
+                now = wait.end_s
                 if item is _END:
                     if self._error is not None:
                         raise self._error
                     self._mark_delivered(None)  # drop_last / buffer-drain leftovers
                     return
                 batch, local_rows = item
-                self.stats.add(wait_time_s=now - wait_start,
+                self.stats.add(wait_time_s=wait.seconds,
                                total_time_s=now - last_emit,
                                batches=1, rows=local_rows)
-                # shuffle_wait: time the training loop sat blocked on the input
-                # pipeline for this batch — the stage the stall fraction sums
-                # (clocked on monotonic, so the timeline leg back-dates)
-                self.observe_traced('shuffle_wait', now - wait_start)
                 if self._telemetry_jsonl is not None and self._telemetry_jsonl.due():
                     # one snapshot serves both legs: the periodic interval
                     # line AND the SLO evaluation (whose ok->breach
@@ -484,6 +476,11 @@ class JaxDataLoader(object):
         return NoopShufflingBuffer()
 
     def _produce(self, out_queue, stop_event):
+        # The producer thread's time is the sum of its stages: the reader's
+        # (pool_wait, shm_map / wire_map, consume), rebatch here, collate and
+        # device_decode per chunk, h2d / device_decode / d2d_wait per batch in
+        # _emit, and put_wait in _put (docs/observability.md).
+        rebatch = stage_span('rebatch', registry=self.telemetry)
         try:
             buffer = self._make_buffer()
             self._active_buffer = buffer
@@ -491,24 +488,33 @@ class JaxDataLoader(object):
                 # Feed the buffer in batch_size slices so a whole-rowgroup chunk (the
                 # iter_columnar fast path) cannot blow past the shuffling buffer's
                 # configured capacity; slices of ndarrays are views, so this is cheap.
+                rebatch.begin()
                 for part in _iter_column_slices(columns, self.batch_size):
                     buffer.add_many(part)
                     while buffer.can_retrieve(self.batch_size):
                         if stop_event.is_set():
                             return
-                        self._emit(buffer.retrieve(self.batch_size), out_queue, stop_event)
+                        batch = buffer.retrieve(self.batch_size)
+                        rebatch.end()
+                        self._emit(batch, out_queue, stop_event)
+                        rebatch.begin()
+                rebatch.end()
                 if stop_event.is_set():
                     return
+            rebatch.begin()
             buffer.finish()
             while buffer.can_retrieve(self.batch_size) and not stop_event.is_set():
                 batch = buffer.retrieve(self.batch_size)
+                rebatch.end()
                 if self._batch_cols_rows(batch) < self.batch_size and self._drop_last:
                     break
                 self._emit(batch, out_queue, stop_event)
+                rebatch.begin()
         except Exception as exc:  # noqa: BLE001 - surface in consumer
             if not stop_event.is_set():
                 self._error = exc
         finally:
+            rebatch.end()
             self._put(_END, out_queue, stop_event)
 
     @staticmethod
@@ -570,27 +576,21 @@ class JaxDataLoader(object):
             self.stats.mirror(**mirrored)
 
     def _sanitize(self, columns):
-        # collate stage: host batch assembly — dtype sanitization + ragged padding
-        collate_start = time.perf_counter()
         passthrough = frozenset()
         stage = self._device_stage
         if stage is not None:
             # host-mode device fields decode HERE (before sanitize, so
             # pad_ragged still applies to them); device-mode fields pass
             # through sanitize raw and decode on chip in _emit
-            dd_start = time.perf_counter()
-            columns, decoded_any = stage.sanitize_decode(columns)
+            with stage_span('device_decode', registry=self.telemetry):
+                columns, decoded_any = stage.sanitize_decode(columns)
             if decoded_any:
                 self.stats.add(device_fallback_batches=1)
-                self.observe_traced('device_decode',
-                                    time.perf_counter() - dd_start,
-                                    start_pc=dd_start)
             passthrough = stage.passthrough_names
-        out = sanitize_columns(columns, self._pad_ragged, self._device_put,
-                               passthrough=passthrough)
-        self.observe_traced('collate', time.perf_counter() - collate_start,
-                            start_pc=collate_start)
-        return out
+        # collate stage: host batch assembly — dtype sanitization + ragged padding
+        with stage_span('collate', registry=self.telemetry):
+            return sanitize_columns(columns, self._pad_ragged, self._device_put,
+                                    passthrough=passthrough)
 
     def _emit(self, columns, out_queue, stop_event):
         local_rows = self._batch_cols_rows(columns)
@@ -598,19 +598,16 @@ class JaxDataLoader(object):
             import jax
             stage = self._device_stage
             recipe = None
-            prepare_s = 0.0
             if stage is not None and not stage.host_mode:
                 # device decode tail, host half: pack/inflate raw payloads
                 # into upload-ready arrays + the static program recipe
-                prep_start = time.perf_counter()
-                columns, recipe = stage.prepare(columns, self._mesh)
-                prepare_s = time.perf_counter() - prep_start
+                with stage_span('device_decode', registry=self.telemetry):
+                    columns, recipe = stage.prepare(columns, self._mesh)
             sharding = self._sharding
             if isinstance(sharding, FieldShardings) and not self._spec_keys_checked:
                 self._spec_keys_checked = True
                 sharding.check_unused(columns.keys())
-            h2d_start = time.perf_counter()
-            with _trace_span('petastorm_tpu.loader.h2d'):
+            with stage_span('h2d', registry=self.telemetry):
                 if self._mesh is not None:
                     batch = {name: jax.make_array_from_process_local_data(
                                  sharding_for_field(sharding, name), col)
@@ -619,8 +616,6 @@ class JaxDataLoader(object):
                 else:
                     batch = jax.device_put(columns, sharding)
                     self.stats.add(per_field_uploads=1)
-            self.observe_traced('h2d', time.perf_counter() - h2d_start,
-                                start_pc=h2d_start)
             if recipe:
                 # device half: the jitted decode+augment program (async
                 # dispatch — the train step synchronizes), then the
@@ -628,25 +623,18 @@ class JaxDataLoader(object):
                 # device field host_only, already decoded in _sanitize) must
                 # not count as a device decode — the stats contract is that a
                 # capture can prove which path ran.
-                finish_start = time.perf_counter()
-                with _trace_span('petastorm_tpu.loader.device_decode'):
+                with stage_span('device_decode', registry=self.telemetry):
                     batch = stage.finish(batch, recipe)
                 self.stats.add(device_decode_batches=1)
-                self.observe_traced(
-                    'device_decode',
-                    prepare_s + time.perf_counter() - finish_start)
-                waited = stage.throttle(batch)
-                if waited:
-                    self.observe_traced('d2d_wait', waited)
+                with stage_span('d2d_wait', registry=self.telemetry):
+                    stage.throttle(batch)
             elif (stage is not None and stage.host_mode
                   and stage.has_transforms):
                 # host-mode backends still apply the declared augment chains
                 # (same jitted math, post-upload) — a CPU fallback run must
                 # train on the same data an accelerator run would
-                t_start = time.perf_counter()
-                batch = stage.apply_transforms(batch)
-                self.observe_traced('device_decode',
-                                    time.perf_counter() - t_start)
+                with stage_span('device_decode', registry=self.telemetry):
+                    batch = stage.apply_transforms(batch)
         else:
             batch = columns
         # Host-local row count travels alongside: with a multi-process mesh the device
@@ -655,12 +643,13 @@ class JaxDataLoader(object):
         self._put((batch, local_rows), out_queue, stop_event)
 
     def _put(self, item, out_queue, stop_event):
-        while not stop_event.is_set():
-            try:
-                out_queue.put(item, timeout=0.1)
-                return
-            except queue.Full:
-                continue
+        with stage_span('put_wait', registry=self.telemetry):
+            while not stop_event.is_set():
+                try:
+                    out_queue.put(item, timeout=0.1)
+                    return
+                except queue.Full:
+                    continue
         if item is _END:
             try:
                 out_queue.put_nowait(_END)
@@ -773,8 +762,7 @@ class JaxDataLoader(object):
             chunk = {name: np.ascontiguousarray(
                          col.reshape((n_batches, batch_size) + col.shape[1:]))
                      for name, col in columns.items()}
-            h2d_start = time.perf_counter()
-            with _trace_span('petastorm_tpu.loader.scan_stream.h2d'):
+            with stage_span('h2d', registry=self.telemetry):
                 if self._mesh is not None:
                     # Same upload contract as __iter__'s mesh path: host-local
                     # chunk rows assemble into the global sharded chunk array
@@ -784,8 +772,6 @@ class JaxDataLoader(object):
                              for name, col in chunk.items()}
                 else:
                     chunk = jax.device_put(chunk, sharding)
-            self.observe_traced('h2d', time.perf_counter() - h2d_start,
-                                start_pc=h2d_start)
             key = (step_fn, n_batches)
             if key not in programs:
                 @jax.jit
@@ -948,19 +934,6 @@ class JaxDataLoader(object):
         return stage.depth
 
     # ------------------------------------------------------------------ telemetry
-
-    def observe_traced(self, stage, dur_s, start_pc=None):
-        """One loader-stage measurement, both legs: the loader's registry
-        histogram and (when the flight recorder is armed) a timeline span.
-        ``start_pc`` is the ``perf_counter`` start; None back-dates by the
-        measured duration (for stages clocked on a different timebase, e.g.
-        the monotonic-clocked ``shuffle_wait``). The stage name is validated
-        against the spans.py catalog by pipecheck's telemetry-names rule."""
-        self.telemetry.observe(stage, dur_s)
-        if _flight.trace_enabled():
-            if start_pc is None:
-                start_pc = time.perf_counter() - dur_s
-            _flight.trace_complete(stage, start_pc, dur_s)
 
     def telemetry_snapshot(self):
         """One JSON-safe telemetry snapshot covering the WHOLE pipeline: the

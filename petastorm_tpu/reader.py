@@ -19,6 +19,7 @@ from petastorm_tpu.fs_utils import (as_arrow_filesystem, check_hdfs_driver,
                                     make_filesystem_factory,
                                     normalize_dataset_url_or_urls)
 from petastorm_tpu.reader_worker import ColumnarBatch, RowGroupWorker, WorkerSetup
+from petastorm_tpu.telemetry.spans import stage_span
 from petastorm_tpu.telemetry.tracing import (merge_trace_events,
                                              set_trace_enabled, trace_enabled,
                                              trace_instant)
@@ -1233,29 +1234,35 @@ class Reader(object):
             except EmptyResultError:
                 self.last_row_consumed = True
                 return
-            if self.ngram is not None:
-                # NGramWindows payload (shared columns + gather starts) -> dense
-                # window-major arrays, one vectorized gather per column. item_id
-                # rides along so delivery accounting / resume see the piece —
-                # and so do the resilience/cache/telemetry sidecars, which
-                # _note_item_consumed below accounts from this rebuilt batch.
-                batch = ColumnarBatch(
-                    self.ngram.windows_as_arrays(batch.columns, batch.starts),
-                    len(batch.starts), item_id=batch.item_id,
-                    retries=getattr(batch, 'retries', 0),
-                    quarantine=getattr(batch, 'quarantine', None),
-                    cache_hit=getattr(batch, 'cache_hit', None),
-                    telemetry=getattr(batch, 'telemetry', None),
-                    breakers=getattr(batch, 'breakers', None),
-                    trace=getattr(batch, 'trace', None),
-                    lineage=getattr(batch, 'lineage', None))
-            self._note_item_consumed(batch)
-            if self._resume_fast_forward and batch.item_id is not None:
-                # Honor a row_cursor from a row-path checkpoint: skip the rows that
-                # were already emitted before the checkpoint (exact-once everywhere).
-                start = self._resume_fast_forward.pop(batch.item_id, 0)
-                if start:
-                    batch = _slice_batch(batch, start)
+            item_id = getattr(batch, 'item_id', None)
+            with stage_span('consume', registry=self._telemetry,
+                            ctx=None if item_id is None
+                            else (item_id[0], item_id[1], 0)):
+                if self.ngram is not None:
+                    # NGramWindows payload (shared columns + gather starts) ->
+                    # dense window-major arrays, one vectorized gather per
+                    # column. item_id rides along so delivery accounting /
+                    # resume see the piece — and so do the resilience/cache/
+                    # telemetry sidecars, which _note_item_consumed below
+                    # accounts from this rebuilt batch.
+                    batch = ColumnarBatch(
+                        self.ngram.windows_as_arrays(batch.columns, batch.starts),
+                        len(batch.starts), item_id=batch.item_id,
+                        retries=getattr(batch, 'retries', 0),
+                        quarantine=getattr(batch, 'quarantine', None),
+                        cache_hit=getattr(batch, 'cache_hit', None),
+                        telemetry=getattr(batch, 'telemetry', None),
+                        breakers=getattr(batch, 'breakers', None),
+                        trace=getattr(batch, 'trace', None),
+                        lineage=getattr(batch, 'lineage', None))
+                self._note_item_consumed(batch)
+                if self._resume_fast_forward and item_id is not None:
+                    # Honor a row_cursor from a row-path checkpoint: skip the
+                    # rows that were already emitted before the checkpoint
+                    # (exact-once everywhere).
+                    start = self._resume_fast_forward.pop(item_id, 0)
+                    if start:
+                        batch = _slice_batch(batch, start)
             if batch.num_rows or include_empty:
                 yield batch
 

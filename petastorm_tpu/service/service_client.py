@@ -52,6 +52,7 @@ from petastorm_tpu.service.wire import (ShmResultDescriptor, client_endpoint,
                                         encode_cost, host_token)
 from petastorm_tpu.telemetry.registry import (MetricsRegistry,
                                               telemetry_enabled)
+from petastorm_tpu.telemetry.spans import stage_span
 from petastorm_tpu.workers import EmptyResultError, TimeoutWaitingForResultError
 
 logger = logging.getLogger(__name__)
@@ -441,9 +442,17 @@ class ServicePool(object):
     def get_results(self, timeout: Optional[float] = None) -> Any:
         """Next result batch; raises ``EmptyResultError`` when all ventilated
         work completed, re-raises worker exceptions shipped over the wire."""
+        # pool_wait: until the message that carries the result; mapping it
+        # is shm_map / wire_map, so the two never overlap
+        wait = stage_span('pool_wait', registry=self.telemetry).begin()
+        try:
+            return self._next_result(timeout, wait)
+        finally:
+            wait.end()
+
+    def _next_result(self, timeout: Optional[float], wait: stage_span) -> Any:
         import zmq
         deadline = None if timeout is None else time.monotonic() + timeout
-        wait_start = time.perf_counter()
         while True:
             if self._stopped:
                 raise RuntimeError('ServicePool is stopped')
@@ -515,23 +524,19 @@ class ServicePool(object):
                     self._rearm_inflight()
                     self._rejoin()
                 continue
-            if kind == b'result':
-                result = self._handle_result(int(bytes(frames[1])),
-                                             frames[2:])
+            if kind == b'result' or kind == b'result_shm':
+                wait.end()
+                if kind == b'result':
+                    with stage_span('wire_map', registry=self.telemetry):
+                        result = self._handle_result(int(bytes(frames[1])),
+                                                     frames[2:])
+                else:
+                    with stage_span('shm_map', registry=self.telemetry):
+                        result = self._handle_shm_result(
+                            int(bytes(frames[1])), frames[2])
                 if result is None:
+                    wait.begin()  # a duplicate or a redelivery: wait on
                     continue
-                if telemetry_enabled():
-                    self.telemetry.observe('pool_wait',
-                                           time.perf_counter() - wait_start)
-                return result[0]
-            if kind == b'result_shm':
-                result = self._handle_shm_result(int(bytes(frames[1])),
-                                                 frames[2])
-                if result is None:
-                    continue
-                if telemetry_enabled():
-                    self.telemetry.observe('pool_wait',
-                                           time.perf_counter() - wait_start)
                 return result[0]
             if kind == b'error':
                 import pickle

@@ -75,6 +75,26 @@ _KNOBS: Dict[str, Any] = {
     'shm_release': ('slot release dominates — raise shm_slots_per_worker',
                     'Releasing shm slots dominates — ROUTER backpressure; more '
                     'slots per worker decouple the ack path.'),
+    'wire_map': ('results ride the ZMQ wire — check the shm ring',
+                 'Deserializing wire-borne results dominates consumer time: '
+                 'a batch larger than a slot or a slot-starved worker falls '
+                 'back from the shm ring — raise shm_slot_bytes / '
+                 'shm_slots_per_worker (shm_fallback_batches counts them).'),
+    'consume': ('per-item reader accounting dominates — check NGram windows',
+                'The reader\'s per-item work after the pool (NGram window '
+                'rebuild, sidecar merges, resume fast-forward) dominates: '
+                'fewer, larger rowgroups amortize it; NGram rebuilds scale '
+                'with window count.'),
+    'rebatch': ('re-batching dominates — match rowgroup rows to batch_size',
+                'Slicing items into batches through the shuffling buffer '
+                'dominates: a batch that spans several rowgroups is copied '
+                'together on the producer thread — rowgroups holding a '
+                'multiple of batch_size rows, or a smaller shuffling buffer, '
+                'shrink the copy.'),
+    'put_wait': ('the training step sets the pace (input is ahead)',
+                 'The producer blocks on a full prefetch queue: the input '
+                 'pipeline delivers faster than the train step consumes — '
+                 'nothing to tune on the input side.'),
     'pool_wait': ('raise workers_count (consumer starved)',
                   'The consumer sits idle in pool.get_results: the worker pool '
                   'cannot keep up — raise workers_count, or remove the '

@@ -5,7 +5,8 @@ from the wait-stage spans the pipeline already records
 The mission line this measures against: the input pipeline should keep the
 accelerator >= 90% busy (``SloPolicy(target_efficiency=0.9)``). The signal
 already exists — ``shuffle_wait`` (training loop blocked on the loader's
-prefetch queue), ``pool_wait`` (consumer blocked in ``pool.get_results``) and
+prefetch queue), ``pool_wait`` (consumer waiting in ``pool.get_results`` for
+a result; mapping it is ``shm_map`` / ``wire_map``, work and not a wait) and
 ``d2d_wait`` (blocked on the prefetch-to-device ring) are exactly the seconds
 the CONSUMER side sat starved — this module just divides it by wall time:
 

@@ -21,14 +21,24 @@ the calling thread's accumulation, so thread-pool workers never race each other,
 and the serialize/slot-wait stages recorded by the process-pool worker main land
 on the same thread that publishes the next batch (they ride one item late —
 still the same process total).
+
+Consumer-side stages (the pools' ``pool_wait`` / ``shm_map`` / ``wire_map``,
+the reader's ``consume``, the loader's stages) pass the registry they belong to
+(``stage_span(stage, registry=...)``). One :class:`stage_span` then writes the
+same ``time.perf_counter`` pair to three views: the registry histogram, the
+flight-recorder event when that recorder is armed, and — in a process that has
+already imported ``jax`` — a ``jax.profiler`` TraceMe while a profiler session
+collects, so the span lands on the device trace's clock under
+:data:`PROFILER_NAMES` (docs/observability.md "Profiler spans").
 """
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from types import TracebackType
-from typing import Any, Dict, List, Optional, Type
+from typing import Any, Dict, List, Optional, Tuple, Type
 
 from petastorm_tpu.telemetry import registry as _registry
 from petastorm_tpu.telemetry import tracing as _tracing
@@ -48,9 +58,16 @@ STAGES = (
     'cache_corrupt',  # detecting+deleting a corrupt entry (worker; count = entries)
     'serialize',      # result -> wire frames (process-pool worker main)
     'shm_slot_wait',  # backpressure wait for a free ring slot (worker main)
-    'shm_map',        # slot view + deserialize on the consumer (pool)
+    'shm_map',        # slot view + CRC verify + deserialize on the consumer (pool)
+    'wire_map',       # deserialize of a result that rode the ZMQ wire (pool)
     'shm_release',    # slot ack back to the producing worker (pool)
-    'pool_wait',      # consumer blocked in pool.get_results (pool)
+    'pool_wait',      # consumer waiting in pool.get_results for a result
+                      # message — ends where shm_map / wire_map begins (pool)
+    'consume',        # the reader's per-item work after get_results returns:
+                      # NGram rebuild, sidecar accounting, resume fast-forward
+                      # (reader)
+    'rebatch',        # slicing reader items into batches through the
+                      # shuffling buffer (loader producer)
     'shuffle_wait',   # consumer blocked on the loader's prefetch queue (loader)
     'collate',        # host batch assembly / sanitize (loader)
     'h2d',            # host->device upload (loader)
@@ -59,6 +76,8 @@ STAGES = (
                       # decode (loader; docs/performance.md)
     'd2d_wait',       # blocked on the prefetch-to-device ring: the oldest
                       # dispatched device batch had not finished (loader)
+    'put_wait',       # the producer blocked on the full prefetch queue: the
+                      # training loop is behind the input pipeline (loader)
     'decode_field',   # ONE field's kernel inside 'decode' — emitted to the
                       # flight-recorder timeline only (never a histogram),
                       # and only while tracing is armed: the per-field leg of
@@ -72,6 +91,18 @@ STAGES = (
     'range_hedge',    # lifetime of one hedged duplicate GET, win or lose
                       # (storage/fetcher.py)
 )
+
+#: stages the loader times; their profiler events are named
+#: ``petastorm_tpu.loader.<stage>``, every other stage's ``petastorm_tpu.<stage>``
+LOADER_STAGES = frozenset({'rebatch', 'shuffle_wait', 'collate', 'h2d',
+                           'device_decode', 'd2d_wait', 'put_wait'})
+
+#: each stage's event name on the jax profiler's host plane. ``shuffle_wait``
+#: is the training loop's input wait, which benchmarks/trace.py names idle
+#: device gaps by as ``petastorm_tpu.loader.wait_input``
+PROFILER_NAMES = {stage: ('petastorm_tpu.loader.' if stage in LOADER_STAGES
+                          else 'petastorm_tpu.') + stage for stage in STAGES}
+PROFILER_NAMES['shuffle_wait'] = 'petastorm_tpu.loader.wait_input'
 
 #: stages whose span ENVELOPES other recorded stages (cache_miss wraps
 #: rowgroup_read+decode) — excluded from time-share attribution so shares of the
@@ -247,12 +278,14 @@ _process_recorder = StageRecorder()
 
 def record_stage(stage: str, seconds: float,
                  trace_args: Optional[Dict[str, Any]] = None) -> None:
-    """Record one observation into the process-wide stage recorder (and, when
-    the flight recorder is armed, a matching trace event back-dated by the
-    measured duration — docs/observability.md "Flight recorder").
-    ``trace_args`` rides only the trace event (never the histogram) — the
-    storage engine uses it to ship per-fetch byte/range/hedge totals to the
-    cost ledger (telemetry/cost_model.py)."""
+    """Record one observation, measured before the call, into the
+    process-wide stage recorder (and, when the flight recorder is armed, a
+    matching trace event back-dated by the measured duration —
+    docs/observability.md "Flight recorder"). The profiler cannot back-date
+    an event, so a stage that should appear on its clock is timed with
+    :class:`stage_span` instead. ``trace_args`` rides only the trace event
+    (never the histogram) — the storage engine uses it to ship per-fetch
+    byte/range/hedge totals to the cost ledger (telemetry/cost_model.py)."""
     _process_recorder.record(stage, seconds)
     if _tracing.trace_enabled():
         _tracing.trace_complete(stage, time.perf_counter() - seconds, seconds,
@@ -264,32 +297,108 @@ def drain_stage_times() -> Optional[Dict[str, Dict[str, Any]]]:
     return _process_recorder.drain()
 
 
+#: jax's TraceMe class, looked up once this process has imported jax
+_trace_me_class: Any = None
+
+
+def _profiler_trace_me() -> Any:
+    """The ``jax.profiler`` TraceMe class, or None in a process that has not
+    imported jax: a span never imports jax itself, so pool worker processes
+    stay free of it."""
+    global _trace_me_class
+    if _trace_me_class is None and 'jax' in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _trace_me_class = TraceAnnotation
+    return _trace_me_class
+
+
+def _ctx_metadata(ctx: Optional[Tuple[int, int, int]]) -> Dict[str, int]:
+    if ctx is None:
+        return {}
+    return {'epoch': ctx[0], 'rowgroup': ctx[1], 'attempt': ctx[2]}
+
+
 class stage_span(object):
-    """Context manager timing one stage into the process recorder:
-    ``with stage_span('decode'): ...``. Near-zero cost when telemetry is
-    disabled (one enabled check, no clock reads). Exceptions propagate; the
-    partial duration is still recorded (a stage that died slow is exactly the
-    signal the bottleneck report wants)."""
+    """One timed stage: ``with stage_span('decode'): ...``, or
+    ``span = stage_span('pool_wait', registry=r).begin()`` ... ``span.end()``
+    where the scope is not a block.
 
-    __slots__ = ('_stage', '_start')
+    One ``time.perf_counter`` pair feeds every view: the histogram
+    (``registry``, else the process recorder that worker sidecars drain), the
+    flight-recorder event when that recorder is armed, and a ``jax.profiler``
+    TraceMe named :data:`PROFILER_NAMES` ``[stage]`` while a profiler session
+    collects in a process that has imported jax (one inactive-TraceMe check
+    otherwise). ``ctx`` is the causal ``(epoch, rowgroup, attempt)`` context
+    of both events; the TraceMe carries it as metadata, encoded only while a
+    profile is collected. ``start_s`` / ``end_s`` / ``seconds`` expose the
+    measurement to callers that keep their own counters. Exceptions
+    propagate; the partial duration is still recorded (a stage that died slow
+    is exactly the signal the bottleneck report wants)."""
 
-    def __init__(self, stage: str) -> None:
+    __slots__ = ('_stage', '_registry', '_ctx', '_trace_me', 'start_s',
+                 'end_s')
+
+    def __init__(self, stage: str,
+                 registry: Optional[_registry.MetricsRegistry] = None,
+                 ctx: Optional[Tuple[int, int, int]] = None) -> None:
         self._stage = stage
-        self._start = 0.0
+        self._registry = registry
+        self._ctx = ctx
+        self._trace_me: Any = None
+        self.start_s = 0.0
+        self.end_s = 0.0
+
+    @property
+    def seconds(self) -> float:
+        """The span's duration (0 until it ended)."""
+        return self.end_s - self.start_s if self.end_s else 0.0
+
+    def begin(self) -> 'stage_span':
+        """Start the span (again, after :meth:`end`: a wait that resumes is
+        one more observation); returns it."""
+        self.end_s = 0.0
+        trace_me = _profiler_trace_me()
+        if trace_me is not None and trace_me.is_enabled():
+            self._trace_me = trace_me(
+                PROFILER_NAMES.get(self._stage, 'petastorm_tpu.' + self._stage),
+                **_ctx_metadata(self._ctx))
+            self._trace_me.__enter__()
+        self.start_s = time.perf_counter()
+        return self
+
+    def set_context(self, ctx: Tuple[int, int, int]) -> None:
+        """Tag the span with a causal context learned while it runs (the
+        ``shm_map`` span learns its batch's rowgroup from the payload)."""
+        self._ctx = ctx
+        if self._trace_me is not None:
+            self._trace_me.set_metadata(**_ctx_metadata(ctx))
+
+    def end(self, record: bool = True) -> None:
+        """End the span and record it; only the first call records.
+        ``record=False`` ends it without a histogram or flight-recorder
+        entry (a wait that ended without what it waited for); a profiler
+        TraceMe, once begun, cannot be withdrawn and still shows the time."""
+        if self.end_s or not self.start_s:
+            return
+        self.end_s = time.perf_counter()
+        if self._trace_me is not None:
+            self._trace_me.__exit__(None, None, None)
+            self._trace_me = None
+        if not record:
+            return
+        duration = self.end_s - self.start_s
+        if self._registry is None:
+            _process_recorder.record(self._stage, duration)
+        else:
+            self._registry.observe(self._stage, duration)
+        if _tracing.trace_enabled():
+            _tracing.trace_complete(self._stage, self.start_s, duration,
+                                    ctx=self._ctx)
 
     def __enter__(self) -> 'stage_span':
-        if _registry.telemetry_enabled() or _tracing.trace_enabled():
-            self._start = time.perf_counter()
-        return self
+        return self.begin()
 
     def __exit__(self, exc_type: Optional[Type[BaseException]],
                  exc: Optional[BaseException],
                  tb: Optional[TracebackType]) -> None:
-        if self._start:
-            duration = time.perf_counter() - self._start
-            _process_recorder.record(self._stage, duration)
-            if _tracing.trace_enabled():
-                # same measurement feeds both views: the histogram (aggregate)
-                # and the flight-recorder timeline (this specific span)
-                _tracing.trace_complete(self._stage, self._start, duration)
-            self._start = 0.0
+        self.end()

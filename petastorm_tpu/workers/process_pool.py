@@ -86,6 +86,7 @@ import time
 from petastorm_tpu.telemetry import tracing as _tracing
 from petastorm_tpu.telemetry.registry import (BYTES_UNIT, MetricsRegistry,
                                               telemetry_enabled)
+from petastorm_tpu.telemetry.spans import stage_span
 from petastorm_tpu.workers import EmptyResultError, TimeoutWaitingForResultError
 
 logger = logging.getLogger(__name__)
@@ -435,12 +436,9 @@ class ProcessPool(object):
             current = self._slot_generation[descriptor.worker_slot]
         if identity is None or current != descriptor.generation:
             return
-        release_start = time.perf_counter()
-        self._dispatch_socket.send_multipart(
-            [identity, b'release', b'%d' % descriptor.ring_slot])
-        if telemetry_enabled():
-            self.telemetry.observe('shm_release',
-                                   time.perf_counter() - release_start)
+        with stage_span('shm_release', registry=self.telemetry):
+            self._dispatch_socket.send_multipart(
+                [identity, b'release', b'%d' % descriptor.ring_slot])
 
     def _handle_done(self, token, attempt=None):
         with self._state_lock:
@@ -716,115 +714,127 @@ class ProcessPool(object):
         poller.register(self._results_socket, zmq.POLLIN)
         poller.register(self._dispatch_socket, zmq.POLLIN)
         deadline = None if timeout is None else time.time() + timeout
-        wait_start = time.perf_counter()
-        while True:
-            if self._hang_results:
-                # Stand-in batch synthesized for a hang-quarantined item: deliver
-                # it like any other result (the quarantine record rides it).
-                return self._hang_results.popleft()
-            # Liveness on the hot path too — not only when results stop: with several
-            # workers, survivors keep producing after one dies, but the dead worker's
-            # in-flight items would otherwise silently vanish. Throttled to ~10Hz
-            # (detection latency is bounded by the 100ms poller timeout anyway);
-            # ventilator.completed() acquires the ventilator lock (shared with the
-            # backpressure condition), so it is only evaluated inside this throttled
-            # window and on poll timeout — never per-result on the hot path.
-            now = time.time()
-            if not self._stopped and now >= self._next_liveness_check:
-                self._next_liveness_check = now + 0.1
-                self._check_liveness()
-            self._dispatch_pending()
-            events = dict(poller.poll(100))
-            if not events:
-                # Hang detection belongs exactly here: the queues are drained and
-                # the consumer is genuinely starved, so heartbeat staleness and
-                # item deadlines measure the workers, not a busy consumer.
-                if self._stopped:
-                    # stop() came from another thread: return to the caller
-                    # before that thread's join() polls these same sockets
-                    raise RuntimeError('the worker pool was stopped')
-                self._check_hangs()
+        # pool_wait: until the message that carries the result; mapping
+        # it is shm_map / wire_map, so the two never overlap
+        wait = stage_span('pool_wait', registry=self.telemetry).begin()
+        try:
+            while True:
                 if self._hang_results:
-                    # a reap just quarantined item(s) — deliver the stand-in
-                    # BEFORE the completed() check can end the epoch
-                    continue
-                if self._ventilator is not None and getattr(self._ventilator, 'error', None):
-                    self.stop()
-                    raise self._ventilator.error
-                if self._ventilator is not None and self._ventilator.completed():
-                    raise EmptyResultError()
-                if deadline is not None and time.time() > deadline:
-                    raise TimeoutWaitingForResultError()
-                continue
-            if self._dispatch_socket in events:
-                frames = self._dispatch_socket.recv_multipart()
-                if len(frames) >= 4 and bytes(frames[1]) == b'ready':
-                    self._handle_ready(frames)
+                    # Stand-in batch synthesized for a hang-quarantined item: deliver
+                    # it like any other result (the quarantine record rides it). The
+                    # wait for it was a hang, not a wait for a result message: the
+                    # watchdog's counters and quarantine record report it.
+                    wait.end(record=False)
+                    return self._hang_results.popleft()
+                # Liveness on the hot path too — not only when results stop: with several
+                # workers, survivors keep producing after one dies, but the dead worker's
+                # in-flight items would otherwise silently vanish. Throttled to ~10Hz
+                # (detection latency is bounded by the 100ms poller timeout anyway);
+                # ventilator.completed() acquires the ventilator lock (shared with the
+                # backpressure condition), so it is only evaluated inside this throttled
+                # window and on poll timeout — never per-result on the hot path.
+                now = time.time()
+                if not self._stopped and now >= self._next_liveness_check:
+                    self._next_liveness_check = now + 0.1
+                    self._check_liveness()
                 self._dispatch_pending()
-            if self._results_socket not in events:
-                continue
-            kind, payload = self._recv()
-            if kind == MSG_HEARTBEAT:
-                self._note_heartbeat(payload)
-                continue
-            if kind == MSG_DONE:
-                self._handle_done(
-                    int(bytes(memoryview(payload[0]))),
-                    attempt=(int(bytes(memoryview(payload[1])))
-                             if len(payload) > 1 else None))
-                continue
-            if kind == MSG_ERROR:
-                exc, tb = pickle.loads(bytes(memoryview(payload[1])))
-                logger.error('Worker failure re-raised in consumer:\n%s', tb)
-                self.stop()
-                raise exc
-            if kind == MSG_RESULT:
-                token = int(bytes(memoryview(payload[0])))
-                payload_bytes = sum(memoryview(frame).nbytes for frame in payload[1:])
-                with self._state_lock:
-                    self._wire_batches += 1
-                    self._zmq_result_bytes += payload_bytes
-                    shm_fallback = self._ring is not None
-                    if shm_fallback:
-                        self._shm_fallback_batches += 1
-                    if token not in self._items or token in self._delivered:
-                        # Duplicate from a re-ventilated item whose first result was
-                        # already delivered (retired token, or delivered-but-not-yet-
-                        # acked) — count it, never deliver it twice.
-                        self._results_dropped += 1
+                events = dict(poller.poll(100))
+                if not events:
+                    # Hang detection belongs exactly here: the queues are drained and
+                    # the consumer is genuinely starved, so heartbeat staleness and
+                    # item deadlines measure the workers, not a busy consumer.
+                    if self._stopped:
+                        # stop() came from another thread: return to the caller
+                        # before that thread's join() polls these same sockets
+                        raise RuntimeError('the worker pool was stopped')
+                    self._check_hangs()
+                    if self._hang_results:
+                        # a reap just quarantined item(s) — deliver the stand-in
+                        # BEFORE the completed() check can end the epoch
                         continue
-                    self._delivered.add(token)
-                if shm_fallback and _tracing.trace_enabled():
-                    # anomaly marker: this result rode the ZMQ wire although the
-                    # shm ring was enabled (oversized / slot-starved / breaker)
-                    _tracing.trace_instant('shm_fallback', args={'token': token})
-                copy_before = self._serializer_bytes_copied()
-                result = self._serializer.deserialize(payload[1:])
-                if telemetry_enabled():
-                    # true per-batch copied bytes: ZMQ frame bytes + the
-                    # serializer's receive-side copies for THIS batch
-                    self.telemetry.observe(
-                        'wire_bytes_copied',
-                        payload_bytes + self._serializer_bytes_copied()
-                        - copy_before, unit=BYTES_UNIT)
-                    self.telemetry.observe('pool_wait',
-                                           time.perf_counter() - wait_start)
-                return result
-            if kind == MSG_RESULT_SHM:
-                result = self._handle_shm_result(payload)
-                if result is not None:
-                    if telemetry_enabled():
-                        self.telemetry.observe('pool_wait',
-                                               time.perf_counter() - wait_start)
-                    return result[0]
-                continue
-            if kind == MSG_STARTED:  # respawned worker joining — expected
-                continue
+                    if self._ventilator is not None and getattr(self._ventilator, 'error',
+                                                                None):
+                        self.stop()
+                        raise self._ventilator.error
+                    if self._ventilator is not None and self._ventilator.completed():
+                        raise EmptyResultError()
+                    if deadline is not None and time.time() > deadline:
+                        raise TimeoutWaitingForResultError()
+                    continue
+                if self._dispatch_socket in events:
+                    frames = self._dispatch_socket.recv_multipart()
+                    if len(frames) >= 4 and bytes(frames[1]) == b'ready':
+                        self._handle_ready(frames)
+                    self._dispatch_pending()
+                if self._results_socket not in events:
+                    continue
+                kind, payload = self._recv()
+                if kind == MSG_HEARTBEAT:
+                    self._note_heartbeat(payload)
+                    continue
+                if kind == MSG_DONE:
+                    self._handle_done(
+                        int(bytes(memoryview(payload[0]))),
+                        attempt=(int(bytes(memoryview(payload[1])))
+                                 if len(payload) > 1 else None))
+                    continue
+                if kind == MSG_ERROR:
+                    exc, tb = pickle.loads(bytes(memoryview(payload[1])))
+                    logger.error('Worker failure re-raised in consumer:\n%s', tb)
+                    self.stop()
+                    raise exc
+                if kind == MSG_RESULT:
+                    token = int(bytes(memoryview(payload[0])))
+                    payload_bytes = sum(memoryview(frame).nbytes for frame in payload[1:])
+                    with self._state_lock:
+                        self._wire_batches += 1
+                        self._zmq_result_bytes += payload_bytes
+                        shm_fallback = self._ring is not None
+                        if shm_fallback:
+                            self._shm_fallback_batches += 1
+                        if token not in self._items or token in self._delivered:
+                            # Duplicate from a re-ventilated item whose first result was
+                            # already delivered (retired token, or delivered-but-not-yet-
+                            # acked) — count it, never deliver it twice.
+                            self._results_dropped += 1
+                            continue
+                        self._delivered.add(token)
+                    wait.end()
+                    if shm_fallback and _tracing.trace_enabled():
+                        # anomaly marker: this result rode the ZMQ wire although
+                        # the shm ring was enabled (oversized / slot-starved /
+                        # breaker)
+                        _tracing.trace_instant('shm_fallback',
+                                               args={'token': token})
+                    with stage_span('wire_map', registry=self.telemetry):
+                        copy_before = self._serializer_bytes_copied()
+                        result = self._serializer.deserialize(payload[1:])
+                        if telemetry_enabled():
+                            # true per-batch copied bytes: ZMQ frame bytes + the
+                            # serializer's receive-side copies for THIS batch
+                            self.telemetry.observe(
+                                'wire_bytes_copied',
+                                payload_bytes + self._serializer_bytes_copied()
+                                - copy_before, unit=BYTES_UNIT)
+                    return result
+                if kind == MSG_RESULT_SHM:
+                    result = self._handle_shm_result(payload, wait)
+                    if result is not None:
+                        return result[0]
+                    if wait.end_s:
+                        wait.begin()  # the frame was dropped after mapping began
+                    continue
+                if kind == MSG_STARTED:  # respawned worker joining — expected
+                    continue
+        finally:
+            wait.end()
 
-    def _handle_shm_result(self, payload):
+    def _handle_shm_result(self, payload, wait):
         """One ``result_shm`` message: validate the descriptor's generation, dedup the
         token, verify the payload CRC, deserialize zero-copy from the slot, ack the
-        slot. Returns ``(payload_obj,)`` to deliver or None to keep polling."""
+        slot. ``wait`` is the caller's ``pool_wait`` span, which ends where mapping
+        the slot begins. Returns ``(payload_obj,)`` to deliver or None to keep
+        polling."""
         from petastorm_tpu.workers.shm_ring import ShmSlotDescriptor
         token = int(bytes(memoryview(payload[0])))
         descriptor = ShmSlotDescriptor.from_bytes(bytes(memoryview(payload[1])))
@@ -846,7 +856,10 @@ class ProcessPool(object):
         if self._ring is None:  # defensive: descriptor without a ring
             self._release_slot(descriptor)
             return None
-        map_start = time.perf_counter()
+        wait.end()
+        # shm_map: slot view + CRC verify + deserialize; copied bytes =
+        # descriptor frame + the serializer's receive-side copies
+        span = stage_span('shm_map', registry=self.telemetry).begin()
         copy_before = self._serializer_bytes_copied()
         views = self._ring.view(descriptor)
         if self._shm_checksum and descriptor.crc is not None:
@@ -854,6 +867,7 @@ class ProcessPool(object):
             if payload_checksum(views) != descriptor.crc:
                 for view in views:
                     view.release()
+                span.end()
                 self._on_shm_corruption(descriptor, token)
                 return None
         with self._state_lock:
@@ -863,24 +877,15 @@ class ProcessPool(object):
         try:
             result = self._serializer.deserialize(views)
             self._shm_breaker.record_success()
-            if _tracing.trace_enabled():
-                # consumer-side leg of the rowgroup's trace: the shm_map span
-                # tagged with the delivered batch's (epoch, rowgroup, attempt),
-                # so the exported timeline stitches worker and consumer tracks
-                item_id = getattr(result, 'item_id', None)
-                ctx = None
-                if item_id is not None:
-                    with self._state_lock:
-                        attempt = self._attempt.get(token, 0)
-                    ctx = (int(item_id[0]), int(item_id[1]), attempt)
-                _tracing.trace_complete(
-                    'shm_map', map_start, time.perf_counter() - map_start,
-                    ctx=ctx)
+            item_id = getattr(result, 'item_id', None)
+            if item_id is not None:
+                # consumer-side leg of the rowgroup's trace: the span carries
+                # the delivered batch's (epoch, rowgroup, attempt), so the
+                # exported timeline stitches worker and consumer tracks
+                with self._state_lock:
+                    attempt = self._attempt.get(token, 0)
+                span.set_context((int(item_id[0]), int(item_id[1]), attempt))
             if telemetry_enabled():
-                # shm_map: slot view + CRC verify + deserialize; copied bytes =
-                # descriptor frame + the serializer's receive-side copies
-                self.telemetry.observe('shm_map',
-                                       time.perf_counter() - map_start)
                 self.telemetry.observe(
                     'wire_bytes_copied',
                     memoryview(payload[1]).nbytes
@@ -896,6 +901,7 @@ class ProcessPool(object):
                     view.release()
                 except BufferError:  # pragma: no cover - a consumer kept a ref
                     pass
+            span.end()
             self._release_slot(descriptor)
 
     def _on_shm_corruption(self, descriptor, token):

@@ -10,7 +10,8 @@ import queue
 import threading
 import time
 
-from petastorm_tpu.telemetry.registry import MetricsRegistry, telemetry_enabled
+from petastorm_tpu.telemetry.registry import MetricsRegistry
+from petastorm_tpu.telemetry.spans import stage_span
 from petastorm_tpu.workers import (EmptyResultError, TimeoutWaitingForResultError,
                                    VentilatedItemProcessedMessage)
 
@@ -182,34 +183,32 @@ class ThreadPool(object):
         and the queue drained; re-raises worker exceptions (reference:
         thread_pool.py:139-172)."""
         deadline = None if timeout is None else time.monotonic() + timeout
-        wait_start = time.perf_counter()
-        while True:
-            try:
-                result = self._results_queue.get_nowait()
-            except queue.Empty:
-                if self._ventilator is not None and getattr(self._ventilator, 'error', None):
-                    self.stop()
-                    raise self._ventilator.error
-                if self._ventilator is not None and self._ventilator.completed():
-                    raise EmptyResultError()
-                if deadline is not None and time.monotonic() > deadline:
-                    raise TimeoutWaitingForResultError()
+        with stage_span('pool_wait', registry=self.telemetry):
+            while True:
                 try:
-                    result = self._results_queue.get(timeout=0.1)
+                    result = self._results_queue.get_nowait()
                 except queue.Empty:
+                    if self._ventilator is not None and getattr(self._ventilator, 'error',
+                                                                None):
+                        self.stop()
+                        raise self._ventilator.error
+                    if self._ventilator is not None and self._ventilator.completed():
+                        raise EmptyResultError()
+                    if deadline is not None and time.monotonic() > deadline:
+                        raise TimeoutWaitingForResultError()
+                    try:
+                        result = self._results_queue.get(timeout=0.1)
+                    except queue.Empty:
+                        continue
+                if isinstance(result, VentilatedItemProcessedMessage):
+                    if self._ventilator is not None:
+                        self._ventilator.processed_item()
                     continue
-            if isinstance(result, VentilatedItemProcessedMessage):
-                if self._ventilator is not None:
-                    self._ventilator.processed_item()
-                continue
-            if isinstance(result, _WorkerError):
-                self.stop()
-                logger.error('Worker failure re-raised in consumer:\n%s', result.tb)
-                raise result.exc
-            if telemetry_enabled():
-                self.telemetry.observe('pool_wait',
-                                       time.perf_counter() - wait_start)
-            return result
+                if isinstance(result, _WorkerError):
+                    self.stop()
+                    logger.error('Worker failure re-raised in consumer:\n%s', result.tb)
+                    raise result.exc
+                return result
 
     def stop(self):
         self._stopped.set()
