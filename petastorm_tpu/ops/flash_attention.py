@@ -1,9 +1,12 @@
 """Pallas TPU flash attention: O(T)-memory blockwise attention on the MXU.
 
 Forward pass is a Pallas kernel (grid over [batch*heads, q-blocks, kv-blocks], online
-log-sum-exp softmax accumulated in VMEM scratch, f32 accumulation on the MXU; float32
-inputs contract at fp32, bf16 inputs in one bf16 pass) that also
-emits the per-row log-sum-exp. Backward is the flash backward: two Pallas kernels (dQ,
+log-sum-exp softmax accumulated in VMEM scratch) that also emits the per-row
+log-sum-exp. The MXU takes the blocks in the inputs' own dtype with float32
+accumulation: bf16 blocks contract as bf16 in one pass, the softmax probabilities
+and dS are rounded to bf16 right before their products, and float32 inputs contract
+at fp32 (``HIGHEST``). The softmax statistics, lse, delta and the accumulators are
+float32 throughout. Backward is the flash backward: two Pallas kernels (dQ,
 and dK/dV) that REMATERIALIZE the score blocks from Q/K and the saved LSE — the
 [T, T] attention matrix never exists in any pass, so training memory is O(T * block),
 sub-quadratic in sequence length.
@@ -12,6 +15,10 @@ Falls back to the XLA path (:func:`petastorm_tpu.ops.ring_attention.dense_attent
 when shapes don't tile (T % block != 0, head_dim not lane-aligned). The kernels compile
 for the TPU and run in Pallas interpret mode on the CPU backend only
 (:func:`pallas_interpret`), so CPU tests exercise the same kernel logic.
+
+Under a causal mask only the blocks that straddle the diagonal build the mask; those
+wholly below it skip it, and those wholly above it do no work and fetch nothing: their
+index maps name the block the step before already holds.
 
 Per-row operands keep the TPU block rule (last two block dims divisible by (8, 128)
 or equal to the array's): the log-sum-exp, the backward's ``delta`` and the query
@@ -51,9 +58,10 @@ def _compiler_params(*dimension_semantics):
 
 
 def _dot_precision(dtype):
-    """Contraction precision of the kernels' matmuls: float32 inputs ask Mosaic
-    for fp32 contraction (``HIGHEST``) — its default lets the MXU take f32
-    operands in one bf16 pass; bf16 inputs keep the one-pass default."""
+    """Contraction precision of the kernels' matmuls, whose operands are in the
+    inputs' dtype: bf16 blocks reach the MXU as bf16 with float32 accumulation at
+    the default precision; float32 inputs ask Mosaic for fp32 contraction
+    (``HIGHEST``), since its default takes f32 operands in one bf16 pass."""
     if jnp.dtype(dtype) == jnp.float32:
         return jax.lax.Precision.HIGHEST
     return jax.lax.Precision.DEFAULT
@@ -69,6 +77,51 @@ def _block_segment_mask(qseg, kseg):
     """[Bq, 1] column, [1, Bk] row of int32 ids -> [Bq, Bk] bool: same packed
     segment, both non-padding (``ops.packing`` convention: 0 = padding)."""
     return (qseg == kseg) & (qseg > 0) & (kseg > 0)
+
+
+def _causal_mask(x, q_first, k_first, fill):
+    """``x`` [Bq, Bk] where query ``q_first + r`` may see key ``k_first + c``,
+    else ``fill``."""
+    shape = x.shape
+    diag = (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            - jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+    return jnp.where(diag >= k_first - q_first, x, fill)
+
+
+def _fold_live_blocks(fold, causal, q_first, k_first, block_q, block_k):
+    """Run one grid step's ``fold(masked)``. Without ``causal`` every block folds
+    unmasked. With it, a block whose keys all precede its queries folds without
+    the causal mask, one that straddles the diagonal with it, and one above it
+    not at all (the index maps, :func:`_causal_kv_block` and
+    :func:`_causal_q_block`, keep its operands from being fetched)."""
+    from jax.experimental import pallas as pl
+    if not causal:
+        fold(False)
+        return
+    live = k_first <= q_first + (block_q - 1)
+    below = k_first + (block_k - 1) <= q_first
+
+    @pl.when(below)
+    def _():
+        fold(False)
+
+    @pl.when(jnp.logical_and(live, jnp.logical_not(below)))
+    def _():
+        fold(True)
+
+
+def _causal_kv_block(i, j, block_q, block_k):
+    """K-side block index of the forward's and dq's grid step (q-block ``i``,
+    k-block ``j``) under a causal mask: past q-block ``i``'s last live k-block the
+    step names that block again, already resident, so nothing is fetched."""
+    return jnp.minimum(j, jax.lax.div(i * block_q + (block_q - 1), block_k))
+
+
+def _causal_q_block(i, j, block_q, block_k):
+    """Q-side block index of the dk/dv grid step (k-block ``i``, q-block ``j``)
+    under a causal mask: before k-block ``i``'s first live q-block the step names
+    that block, which the first live step then finds resident."""
+    return jnp.maximum(j, jax.lax.div(i * block_k, block_q))
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, *rest, causal, segmented, block_q, block_k,
@@ -95,17 +148,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, causal, segmented, block_q, block_
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _fold():
-        q = q_ref[0].astype(jnp.float32)                       # [Bq, D]
-        k = k_ref[0].astype(jnp.float32)                       # [Bk, D]
-        v = v_ref[0].astype(jnp.float32)                       # [Bk, D]
-        s = _dot(q, k, ((1,), (1,)), precision) * scale          # [Bq, Bk]
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+    def _fold(masked):
+        v = v_ref[0]                                           # [Bk, D]
+        s = _dot(q_ref[0], k_ref[0], ((1,), (1,)), precision) * scale  # [Bq, Bk]
+        if masked:
+            s = _causal_mask(s, qi * block_q, ki * block_k, _NEG_INF)
         if segmented:
             s = jnp.where(_block_segment_mask(qseg_ref[0], kseg_ref[0]), s,
                           _NEG_INF)
@@ -118,17 +165,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, causal, segmented, block_q, block_
             p = jnp.where(s > _NEG_INF / 2, p, 0.0)
         corr = jnp.exp(m_prev - m_new)                         # [Bq, 1]
         l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + _dot(p, v, ((1,), (0,)), precision)
+        acc_scr[:] = acc_scr[:] * corr + _dot(p.astype(v.dtype), v, ((1,), (0,)),
+                                              precision)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    if causal:
-        # Blocks strictly above the diagonal contribute nothing: skip their matmuls.
-        @pl.when(ki * block_k <= qi * block_q + (block_q - 1))
-        def _():
-            _fold()
-    else:
-        _fold()
+    _fold_live_blocks(_fold, causal, qi * block_q, ki * block_k, block_q, block_k)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -148,19 +190,28 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, causal, segmented, block_q, block_
             lse_ref[0] = m_scr[:, :1] + jnp.log(l_scr[:, :1])
 
 
-def _segment_operands(segments, block_q, block_k, heads, kv_outer):
+def _inner_block(clamp, causal, block_q, block_k):
+    """``(i, j) -> block`` on a grid's inner axis: ``clamp``'s under a causal mask,
+    else ``j``."""
+    if causal:
+        return lambda i, j: clamp(i, j, block_q, block_k)
+    return lambda i, j: j
+
+
+def _segment_operands(segments, block_q, block_k, heads, kv_outer, inner):
     """Block specs and operands for the [B, T] packed-segment ids (shared across
     the ``heads`` interleaved into the BH dim): a [B, T, 1] column for the query
     block and a [B, 1, T] row for the key block. The grid is (bh, q-block,
-    k-block), or (bh, k-block, q-block) with ``kv_outer``."""
+    k-block), or (bh, k-block, q-block) with ``kv_outer``; ``inner`` maps
+    ``(i, j)`` to the inner axis' block, as the kernel's other operands on it do."""
     from jax.experimental import pallas as pl
     h = heads
     if kv_outer:
-        qmap = lambda b, i, j: (b // h, j, 0)  # noqa: E731
+        qmap = lambda b, i, j: (b // h, inner(i, j), 0)  # noqa: E731
         kmap = lambda b, i, j: (b // h, 0, i)  # noqa: E731
     else:
         qmap = lambda b, i, j: (b // h, i, 0)  # noqa: E731
-        kmap = lambda b, i, j: (b // h, 0, j)  # noqa: E731
+        kmap = lambda b, i, j: (b // h, 0, inner(i, j))  # noqa: E731
     specs = [pl.BlockSpec((1, block_q, 1), qmap),
              pl.BlockSpec((1, 1, block_k), kmap)]
     return specs, [segments[:, :, None], segments[:, None, :]]
@@ -182,15 +233,13 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret, segments=None,
                                block_q=block_q, block_k=block_k, scale=scale,
                                precision=_dot_precision(q.dtype))
     grid = (bh, nq, nk)
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-    ]
+    kv = _inner_block(_causal_kv_block, causal, block_q, block_k)
+    kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, kv(i, j), 0))
+    in_specs = [pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)), kspec, kspec]
     operands = [q, k, v]
     if segmented:
         seg_specs, seg_operands = _segment_operands(segments, block_q, block_k,
-                                                    heads, False)
+                                                    heads, False, kv)
         in_specs += seg_specs
         operands += seg_operands
     return pl.pallas_call(
@@ -211,21 +260,20 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret, segments=None,
     )(*operands)
 
 
-def _rematerialized_p_ds(q, k, v, do, lse, delta, qi, ki, causal, block_q, block_k,
-                         scale, precision, seg_mask=None):
+def _rematerialized_p_ds(q, k, v, do, lse, delta, q_first, k_first, masked, scale,
+                         precision, seg_mask=None):
     """Shared backward-block math: replay P from (Q, K, LSE), form dS.
 
     Returns (p, ds), both [Bq, Bk] fp32. ``delta = rowsum(dO * O)`` is the softmax
     jacobian's diagonal correction (flash-attention backward identity); ``lse``
-    and ``delta`` are [Bq, 1] columns.
+    and ``delta`` are [Bq, 1] columns. ``masked`` applies the causal mask of the
+    block whose first query and key are ``q_first`` and ``k_first``.
     ``seg_mask`` re-applies the forward's segment confinement (the replayed
     exp(s - lse) is only meaningful where the forward attended)."""
     s = _dot(q, k, ((1,), (1,)), precision) * scale
     p = jnp.exp(s - lse)                                        # [Bq, Bk]
-    if causal:
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        p = jnp.where(q_pos >= k_pos, p, 0.0)
+    if masked:
+        p = _causal_mask(p, q_first, k_first, 0.0)
     if seg_mask is not None:
         p = jnp.where(seg_mask, p, 0.0)
     dp = _dot(do, v, ((1,), (1,)), precision)                  # [Bq, Bk]
@@ -251,28 +299,20 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def _fold():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
+    def _fold(masked):
+        k = k_ref[0]
         seg_mask = (_block_segment_mask(qseg_ref[0], kseg_ref[0])
                     if segmented else None)
-        _, ds = _rematerialized_p_ds(q, k, v, do, lse_ref[0], delta_ref[0], qi, ki,
-                                     causal, block_q, block_k, scale, precision,
-                                     seg_mask)
-        dq_scr[:] = dq_scr[:] + _dot(ds, k, ((1,), (0,)), precision) * scale
+        _, ds = _rematerialized_p_ds(q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0],
+                                     delta_ref[0], qi * block_q, ki * block_k, masked,
+                                     scale, precision, seg_mask)
+        dq_scr[:] = dq_scr[:] + _dot(ds.astype(k.dtype), k, ((1,), (0,)), precision)
 
-    if causal:
-        @pl.when(ki * block_k <= qi * block_q + (block_q - 1))
-        def _():
-            _fold()
-    else:
-        _fold()
+    _fold_live_blocks(_fold, causal, qi * block_q, ki * block_k, block_q, block_k)
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
@@ -294,30 +334,21 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _fold():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
+    def _fold(masked):
+        q, do = q_ref[0], do_ref[0]
         seg_mask = (_block_segment_mask(qseg_ref[0], kseg_ref[0])
                     if segmented else None)
-        p, ds = _rematerialized_p_ds(q, k, v, do, lse_ref[0], delta_ref[0], qi, ki,
-                                     causal, block_q, block_k, scale, precision,
-                                     seg_mask)
-        dv_scr[:] = dv_scr[:] + _dot(p, do, ((0,), (0,)), precision)
-        dk_scr[:] = dk_scr[:] + _dot(ds, q, ((0,), (0,)), precision) * scale
+        p, ds = _rematerialized_p_ds(q, k_ref[0], v_ref[0], do, lse_ref[0],
+                                     delta_ref[0], qi * block_q, ki * block_k, masked,
+                                     scale, precision, seg_mask)
+        dv_scr[:] = dv_scr[:] + _dot(p.astype(do.dtype), do, ((0,), (0,)), precision)
+        dk_scr[:] = dk_scr[:] + _dot(ds.astype(q.dtype), q, ((0,), (0,)), precision)
 
-    if causal:
-        # q-blocks entirely above the diagonal (every q_pos < k_pos) contribute nothing
-        @pl.when(qi * block_q + (block_q - 1) >= ki * block_k)
-        def _():
-            _fold()
-    else:
-        _fold()
+    _fold_live_blocks(_fold, causal, qi * block_q, ki * block_k, block_q, block_k)
 
     @pl.when(qi == nq - 1)
     def _finalize():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
@@ -336,15 +367,16 @@ def _flash_backward(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
                     keepdims=True)                                # [BH, T, 1]
 
+    kv = _inner_block(_causal_kv_block, causal, block_q, block_k)
     qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+    kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, kv(i, j), 0))
     qcol = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
 
     dq_in_specs = [qspec, kspec, kspec, qspec, qcol, qcol]
     dq_operands = [q, k, v, do, lse, delta]
     if segmented:
         seg_specs, seg_operands = _segment_operands(segments, block_q, block_k,
-                                                    heads, False)
+                                                    heads, False, kv)
         dq_in_specs += seg_specs
         dq_operands += seg_operands
     dq = pl.pallas_call(
@@ -361,14 +393,15 @@ def _flash_backward(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
     )(*dq_operands)
 
     # dK/dV iterate the OTHER way: outer over k-blocks, inner over q-blocks.
+    qb = _inner_block(_causal_q_block, causal, block_q, block_k)
     kspec_o = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
-    qspec_i = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0))
-    qcol_i = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0))
+    qspec_i = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, qb(i, j), 0))
+    qcol_i = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, qb(i, j), 0))
     dkv_in_specs = [qspec_i, kspec_o, kspec_o, qspec_i, qcol_i, qcol_i]
     dkv_operands = [q, k, v, do, lse, delta]
     if segmented:
         seg_specs, seg_operands = _segment_operands(segments, block_q, block_k,
-                                                    heads, True)
+                                                    heads, True, qb)
         dkv_in_specs += seg_specs
         dkv_operands += seg_operands
     dk, dv = pl.pallas_call(
@@ -392,24 +425,23 @@ def _tiles(t, d, block_q, block_k):
     return t % block_q == 0 and t % block_k == 0 and d % _LANE == 0
 
 
-# 'auto' preference order: 256 first (the measured default — keeps behavior
-# identical for every shape that already tiled), then 128 to widen Pallas
-# coverage (e.g. T=384, T=1920). Both MXU/VPU-lane aligned.
-_BLOCK_CANDIDATES = (256, 128)
+# 'auto' preference order: the largest that divides T. On one TPU v5e (bf16,
+# BH 32, T 2,048, D 128, causal) the forward, dq and dk/dv kernels took 1.88 ms
+# together at 512 x 512 tiles against 3.53 ms at 256 x 256; 128 widens Pallas
+# coverage to shapes such as T = 384.
+_BLOCK_CANDIDATES = (512, 256, 128)
 
 
 def _resolve_blocks(t, block_q, block_k):
     """Turn ``'auto'`` block sizes into concrete tile sizes for sequence
     length ``t``. Deterministic in (t, request), so the custom-vjp forward and
-    backward always resolve identically. When nothing divides ``t`` the 256
-    placeholder simply fails ``_tiles`` and the dense path runs, exactly like
-    an explicit non-dividing request."""
+    backward always resolve identically. When nothing divides ``t`` the first
+    candidate stays as a placeholder that fails ``_tiles``, and the dense path
+    runs, exactly like an explicit non-dividing request."""
     def one(req):
         if req == 'auto':
-            for cand in _BLOCK_CANDIDATES:
-                if t % cand == 0:
-                    return cand
-            return 256
+            return next((c for c in _BLOCK_CANDIDATES if t % c == 0),
+                        _BLOCK_CANDIDATES[0])
         return req
     return one(block_q), one(block_k)
 
@@ -429,8 +461,8 @@ def flash_attention(q, k, v, causal=False, block_q='auto', block_k='auto'):
     :func:`~petastorm_tpu.ops.ring_attention.dense_attention`). Exact; both passes run
     as Pallas TPU kernels when shapes tile (XLA dense fallback otherwise), with
     O(T * block) memory in forward AND backward. Block sizes default to
-    ``'auto'``: 256 when it divides T (the measured default), else 128 — pass
-    ints to pin them (e.g. from a tile-size sweep)."""
+    ``'auto'``: the largest of 512, 256 and 128 that divides T — pass ints to
+    pin them (e.g. from a tile-size sweep)."""
     return _attention_impl(q, k, v, causal, block_q, block_k)
 
 

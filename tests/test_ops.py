@@ -1,5 +1,7 @@
 """On-device op tests: ring attention exactness vs dense, image ops (CPU 8-dev mesh)."""
 
+import importlib
+
 import numpy as np
 import pytest
 
@@ -200,6 +202,136 @@ class TestFlashAttention:
                 np.asarray(gd, dtype=np.float32),
                 atol=0.25, rtol=0.1, err_msg='d{} mismatch'.format(name))
 
+    # Relative L2 bound for bf16 operands on the MXU (p and dS rounded to bf16,
+    # outputs in bf16): the kernels read ~0.002-0.0025 here, dense attention on
+    # e4m3-rounded inputs 0.04-0.06.
+    BF16_REL_L2 = 0.01
+
+    @pytest.mark.parametrize('segmented', [False, True])
+    @pytest.mark.parametrize('causal', [False, True])
+    def test_bf16_matches_dense_at_highest(self, causal, segmented):
+        """bf16 inputs through the kernels (4 x 4 blocks of 128) against dense
+        attention at float32 ``highest`` on the same values: output and
+        dq/dk/dv within BF16_REL_L2, which dense attention on e4m3-rounded
+        inputs exceeds."""
+        from petastorm_tpu.ops.flash_attention import (flash_attention,
+                                                       flash_attention_segmented)
+        from petastorm_tpu.ops.packing import masked_dense_attention, segment_mask
+        rng = np.random.RandomState(11)
+        shape = (1, 512, 2, 128)
+        q, k, v, g = (jnp.asarray(rng.randn(*shape), jnp.bfloat16) for _ in range(4))
+        if segmented:
+            seg = np.zeros((1, 512), np.int32)
+            seg[0, :200], seg[0, 200:430], seg[0, 430:480] = 1, 2, 3
+            segments = jnp.asarray(seg)
+            mask = segment_mask(segments, segments, causal=causal)
+            flash = lambda a, b_, c: flash_attention_segmented(  # noqa: E731
+                a, b_, c, segments, causal, 128, 128)
+            dense = lambda a, b_, c: masked_dense_attention(a, b_, c, mask)  # noqa: E731
+        else:
+            flash = lambda a, b_, c: flash_attention(a, b_, c, causal, 128, 128)  # noqa: E731
+            dense = lambda a, b_, c: dense_attention(a, b_, c, causal=causal)  # noqa: E731
+
+        def out_and_grads(fn, *xs):
+            out, vjp = jax.vjp(fn, *xs[:3])
+            return [np.asarray(x, np.float64) for x in (out,) + vjp(xs[3].astype(out.dtype))]
+
+        def rel_l2(got, want):
+            return [np.linalg.norm(a - b) / np.linalg.norm(b) for a, b in zip(got, want)]
+
+        f32 = lambda xs: [x.astype(jnp.float32) for x in xs]  # noqa: E731
+        with jax.default_matmul_precision('highest'):
+            want = out_and_grads(dense, *f32((q, k, v, g)))
+            control = rel_l2(out_and_grads(dense, *f32(
+                x.astype(jnp.float8_e4m3fn) for x in (q, k, v, g))), want)
+        got = out_and_grads(flash, q, k, v, g)
+        assert flash(q, k, v).dtype == jnp.bfloat16
+        errs = rel_l2(got, want)
+        for name, err, ctl in zip(('out', 'dq', 'dk', 'dv'), errs, control):
+            assert err <= self.BF16_REL_L2, (name, err)
+            assert ctl > self.BF16_REL_L2, (name, ctl)
+
+    @pytest.mark.parametrize('segmented', [False, True])
+    @pytest.mark.parametrize('blocks', [(128, 128), (256, 128), (128, 256)])
+    def test_clamped_index_maps_change_nothing(self, monkeypatch, blocks, segmented):
+        """Causal steps above the diagonal name an already-resident block instead of
+        their own; with the maps left unclamped the kernels give bit-identical
+        output and gradients (interpret mode, bf16)."""
+        fa = importlib.import_module('petastorm_tpu.ops.flash_attention')
+        rng = np.random.RandomState(12)
+        shape = (1, 512, 2, 128)
+        q, k, v, g = (jnp.asarray(rng.randn(*shape), jnp.bfloat16) for _ in range(4))
+        seg = np.ones((1, 512), np.int32)
+        seg[0, 300:] = 2
+        segments = jnp.asarray(seg)
+
+        def run():
+            if segmented:
+                fn = lambda a, b_, c: fa.flash_attention_segmented(  # noqa: E731
+                    a, b_, c, segments, True, *blocks)
+            else:
+                fn = lambda a, b_, c: fa.flash_attention(a, b_, c, True, *blocks)  # noqa: E731
+            out, vjp = jax.vjp(fn, q, k, v)
+            return [np.asarray(x, np.float32) for x in (out,) + vjp(g)]
+
+        clamped = run()
+        monkeypatch.setattr(fa, '_causal_kv_block', lambda i, j, bq, bk: j)
+        monkeypatch.setattr(fa, '_causal_q_block', lambda i, j, bq, bk: j)
+        for a, b_, name in zip(clamped, run(), ('out', 'dq', 'dk', 'dv')):
+            np.testing.assert_array_equal(a, b_, err_msg=name)
+
+    @pytest.mark.parametrize('blocks', [(128, 128), (256, 128), (128, 256)])
+    def test_causal_index_maps_refetch_nothing_dead(self, blocks):
+        """Along each grid row's inner axis, a step above the diagonal names the
+        block its neighbour on the live side already holds; a live step names its
+        own block."""
+        fa = importlib.import_module('petastorm_tpu.ops.flash_attention')
+        bq, bk = blocks
+        t = 1024
+        nq, nk = t // bq, t // bk
+        for i in range(nq):           # forward and dq: (q-block i, k-block j)
+            kv = [int(fa._causal_kv_block(i, j, bq, bk)) for j in range(nk)]
+            live = [j for j in range(nk) if j * bk <= i * bq + bq - 1]
+            assert kv[:len(live)] == live
+            assert set(kv[len(live):]) <= {live[-1]}
+        for i in range(nk):           # dk/dv: (k-block i, q-block j)
+            qb = [int(fa._causal_q_block(i, j, bq, bk)) for j in range(nq)]
+            live = [j for j in range(nq) if j * bq + bq - 1 >= i * bk]
+            dead = nq - len(live)
+            assert qb[dead:] == live
+            assert set(qb[:dead]) <= {live[0]}
+
+    @pytest.mark.parametrize('blocks', [(128, 128), (256, 128), (128, 256)])
+    def test_causal_steps_split_below_and_diagonal(self, blocks):
+        """Each causal grid step folds without the mask when all its keys precede
+        all its queries, with it when it straddles the diagonal, and not at all
+        above it (a Pallas grid in interpret mode records which)."""
+        from jax.experimental import pallas as pl
+        fa = importlib.import_module('petastorm_tpu.ops.flash_attention')
+        bq, bk = blocks
+        t = 1024
+        nq, nk = t // bq, t // bk
+
+        def kernel(o_ref):
+            qi, ki = pl.program_id(0), pl.program_id(1)
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+            def fold(masked):
+                o_ref[...] = jnp.full_like(o_ref, 2 if masked else 1)
+            fa._fold_live_blocks(fold, True, qi * bq, ki * bk, bq, bk)
+
+        out = pl.pallas_call(
+            kernel, out_shape=jax.ShapeDtypeStruct((8 * nq, 128 * nk), jnp.int32),
+            grid=(nq, nk), out_specs=pl.BlockSpec((8, 128), lambda i, j: (i, j)),
+            interpret=True)()
+        steps = np.asarray(out)[::8, ::128]
+        for i in range(nq):
+            for j in range(nk):
+                q_lo, q_hi, k_lo, k_hi = i * bq, i * bq + bq - 1, j * bk, j * bk + bk - 1
+                want = 0 if k_lo > q_hi else 1 if k_hi <= q_lo else 2
+                assert steps[i, j] == want, (i, j)
+        assert {1, 2} <= set(steps.ravel().tolist())
+
 
 class TestImageOps:
     def test_normalize(self):
@@ -282,18 +414,26 @@ class TestRandomIndexShuffle:
 
 
 class TestFlashAutoBlocks:
-    """'auto' block resolution: 256 when it divides T (identical to the old
-    fixed default), else 128 (widening Pallas coverage to shapes the fixed-256
-    default silently sent down the dense path); non-tiling shapes still take
-    the dense path."""
+    """'auto' block resolution: the largest of 512, 256 and 128 that divides T
+    (128 widens Pallas coverage to shapes a fixed 256 sent down the dense
+    path); non-tiling shapes still take the dense path."""
 
     def test_resolution_preference(self):
         from petastorm_tpu.ops.flash_attention import _resolve_blocks
-        assert _resolve_blocks(512, 'auto', 'auto') == (256, 256)
-        assert _resolve_blocks(8192, 'auto', 'auto') == (256, 256)
+        assert _resolve_blocks(512, 'auto', 'auto') == (512, 512)
+        assert _resolve_blocks(8192, 'auto', 'auto') == (512, 512)
+        assert _resolve_blocks(768, 'auto', 'auto') == (256, 256)
         assert _resolve_blocks(384, 'auto', 'auto') == (128, 128)
-        assert _resolve_blocks(100, 'auto', 'auto') == (256, 256)  # -> dense
+        assert _resolve_blocks(100, 'auto', 'auto') == (512, 512)  # -> dense
         assert _resolve_blocks(384, 64, 'auto') == (64, 128)  # ints pass through
+
+    def test_t1536_takes_512(self):
+        """512 divides 1,536 (three blocks); 256 is never reached."""
+        from petastorm_tpu.ops.flash_attention import _resolve_blocks, _use_pallas
+        assert _resolve_blocks(1536, 'auto', 'auto') == (512, 512)
+        assert _resolve_blocks(1536, 'auto', 256) == (512, 256)
+        x = jnp.zeros((1, 1536, 2, 128), jnp.bfloat16)
+        assert _use_pallas(x, x, 'auto', 'auto')
 
     def test_dispatch_predicate(self):
         from petastorm_tpu.ops.flash_attention import _use_pallas

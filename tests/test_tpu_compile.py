@@ -21,7 +21,8 @@ from petastorm_tpu.ops.raw_decode import stored_inflate
 fa = importlib.import_module('petastorm_tpu.ops.flash_attention')
 
 BH, T, D, HEADS = 8, 2048, 128, 4
-BLOCK = 256
+# the tiles 'auto' can pick at T = 2,048 on the main path
+BLOCKS = pytest.mark.parametrize('block', [256, 512], ids=['block256', 'block512'])
 
 
 @pytest.fixture(scope='module')
@@ -56,40 +57,61 @@ def _compile(fn, *args):
 DTYPES = pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
 
 
+@BLOCKS
 @DTYPES
-def test_flash_forward_compiles(one_chip, dtype):
+def test_flash_forward_compiles(one_chip, dtype, block):
     q = _spec(one_chip, (BH, T, D), dtype)
-    hlo = _compile(lambda q, k, v: fa._flash_forward(q, k, v, True, BLOCK, BLOCK, False),
+    hlo = _compile(lambda q, k, v: fa._flash_forward(q, k, v, True, block, block, False),
                    q, q, q)
     assert 'tpu_custom_call' in hlo
 
 
+@BLOCKS
 @DTYPES
-def test_flash_backward_compiles(one_chip, dtype):
+def test_flash_backward_compiles(one_chip, dtype, block):
     q = _spec(one_chip, (BH, T, D), dtype)
     lse = _spec(one_chip, (BH, T, 1), jnp.float32)
     hlo = _compile(lambda q, k, v, o, lse, do: fa._flash_backward(
-        q, k, v, o, lse, do, True, BLOCK, BLOCK, False), q, q, q, q, lse, q)
+        q, k, v, o, lse, do, True, block, block, False), q, q, q, q, lse, q)
     assert hlo.count('tpu_custom_call') >= 2  # the dQ and the dK/dV kernels
 
 
-def test_segmented_flash_forward_compiles(one_chip):
+@BLOCKS
+def test_segmented_flash_forward_compiles(one_chip, block):
     q = _spec(one_chip, (BH, T, D), jnp.bfloat16)
     segments = _spec(one_chip, (BH // HEADS, T), jnp.int32)
     hlo = _compile(lambda q, k, v, s: fa._flash_forward(
-        q, k, v, True, BLOCK, BLOCK, False, segments=s, heads=HEADS), q, q, q, segments)
+        q, k, v, True, block, block, False, segments=s, heads=HEADS), q, q, q, segments)
     assert 'tpu_custom_call' in hlo
 
 
-def test_segmented_flash_backward_compiles(one_chip):
+@BLOCKS
+def test_segmented_flash_backward_compiles(one_chip, block):
     """The segment ids ride both grid orders: (bh, q, k) for dQ, (bh, k, q) for dK/dV."""
     q = _spec(one_chip, (BH, T, D), jnp.bfloat16)
     lse = _spec(one_chip, (BH, T, 1), jnp.float32)
     segments = _spec(one_chip, (BH // HEADS, T), jnp.int32)
     hlo = _compile(lambda q, k, v, o, lse, do, s: fa._flash_backward(
-        q, k, v, o, lse, do, True, BLOCK, BLOCK, False, segments=s, heads=HEADS),
+        q, k, v, o, lse, do, True, block, block, False, segments=s, heads=HEADS),
         q, q, q, q, lse, q, segments)
     assert hlo.count('tpu_custom_call') >= 2
+
+
+@BLOCKS
+def test_flash_kernels_keep_the_roofline_signatures(one_chip, monkeypatch, block):
+    """One attention call's forward and backward, compiled for the chip, hold exactly
+    one forward, one dq and one dk/dv kernel that the ``flash_roofline`` metric's
+    ``kernel_call`` recognises by their operand and result shapes."""
+    from benchmarks.metrics.flash_roofline import kernel_call
+    monkeypatch.setattr(fa, 'pallas_interpret', lambda: False)  # compile for the chip
+    x = _spec(one_chip, (BH // HEADS, T, HEADS, D), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, True, block, block).astype(jnp.float32).sum()
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+    calls = [kernel_call(line.strip()) for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(calls) == [(kind, BH, T, D, 2) for kind in ('bwd_dkv', 'bwd_dq', 'fwd')]
 
 
 def test_stored_inflate_compiles(one_chip):
