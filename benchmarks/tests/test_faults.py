@@ -4,19 +4,11 @@ import os
 
 import pytest
 
+from conftest import fault_cases
 from benchmarks import harness
 
 
-@pytest.mark.parametrize('name,fault', [
-    ('resnet50.jpeg_stream', 'unchanged'),
-    ('resnet50.jpeg_stream', 'half_batch'),
-    ('resnet50.jpeg_stream', 'altered'),
-    ('resnet50.dct_device', 'altered'),
-    ('cgpt1p3b.tokens_stream', 'unchanged'),
-    ('cgpt1p3b.tokens_stream', 'half_batch'),
-    ('cgpt1p3b.tokens_stream', 'altered'),
-    ('resnet50.jpeg_dp4', 'no_exchange'),
-])
+@pytest.mark.parametrize('name,fault', fault_cases())
 def test_fault_fails_the_check(tiny, name, fault):
     import jax
     cell = tiny(name)

@@ -2,16 +2,15 @@
 harness's pieces (the command itself refuses the CPU)."""
 import json
 import os
+import shutil
 import subprocess
 import sys
 
 import pytest
 
-from conftest import ROOT, TINY_CONFIGS
+from conftest import (ROOT, TINY, first_one_chip_cells, load_json, rehearsed_cells,
+                      spec_with_extra_cells, tiny_file, tiny_rig)
 from benchmarks import harness
-
-CELLS = ['resnet50.jpeg_stream', 'cgpt1p3b.tokens_stream', 'resnet50.dct_device',
-         'resnet50.jpeg_dp4']
 
 
 def _devices(cell):
@@ -23,7 +22,7 @@ def _devices(cell):
     return devices[:cell.chips]
 
 
-@pytest.mark.parametrize('name', CELLS)
+@pytest.mark.parametrize('name', [w['name'] for w in rehearsed_cells()])
 def test_cell_runs_and_metrics_read(tiny, name):
     cell = tiny(name)
     result = harness.run(cell, 2 ** 31 + 7, 1.0, devices=_devices(cell),
@@ -38,7 +37,7 @@ def test_cell_runs_and_metrics_read(tiny, name):
         cell.reader(metric['name']).read(result['_run'])
 
 
-@pytest.mark.parametrize('name', ['resnet50.jpeg_stream', 'cgpt1p3b.tokens_stream'])
+@pytest.mark.parametrize('name', first_one_chip_cells())
 def test_program_matches_reference_in_float32(tiny, tmp_path, name):
     """With the program computing in float32 the check's numbers fall to
     round-off: the reference follows the program's equations."""
@@ -130,7 +129,7 @@ def test_new_mix_needs_new_files_only(tiny, tmp_path, kind):
         mix.pop('transform')
         mix['reader']['pool'] = 'thread'
         mix['store'] = {'kind': 'png', 'version': 1, 'seed': 98, 'rows': 24, 'labels': 10,
-                        'hw': TINY_CONFIGS['resnet50_imagenet']['image_hw'], 'mid_amp': 80,
+                        'hw': tiny('resnet50.jpeg_stream').cfg['image_hw'], 'mid_amp': 80,
                         'tex_amp': 12, 'rowgroup_size_mb': 1, 'files': 2}
     else:
         mix['store'].update(seed=99, rows=24, quality=75)
@@ -155,19 +154,94 @@ def test_command_refuses_the_cpu():
     assert proc.stdout.strip() == ''
 
 
+def _benchmark_copy(dst):
+    """``BENCHMARK.json`` and the benchmark's files, copied under ``dst``."""
+    shutil.copy(os.path.join(ROOT, 'BENCHMARK.json'), dst)
+    shutil.copytree(harness.BENCH_DIR, os.path.join(dst, 'benchmarks'),
+                    ignore=shutil.ignore_patterns('.cache', '__pycache__'))
+
+
 def test_command_fails_without_the_program(tmp_path):
     """A directory with only BENCHMARK.json and the benchmark's files is no system."""
-    import shutil
-    shutil.copy(os.path.join(ROOT, 'BENCHMARK.json'), tmp_path)
-    shutil.copytree(harness.BENCH_DIR, tmp_path / 'benchmarks',
-                    ignore=shutil.ignore_patterns('.cache', '__pycache__'))
+    _benchmark_copy(tmp_path)
     proc = _command(['--workload', 'resnet50.jpeg_stream', '--seed', '1', '--seconds', '1',
                      '--trace', '0'], str(tmp_path), {'JAX_PLATFORMS': ''})
     assert proc.returncode != 0
     assert proc.stdout.strip() == ''
 
 
+def test_every_config_and_mix_has_a_tiny_file():
+    spec = spec_with_extra_cells()
+    missing = ['configs/' + c['name'] for c in spec['configs']
+               if not tiny_file('configs', c['name'])]
+    missing += ['traffic/' + mix for mix in sorted({w['traffic'] for w in spec['workloads']})
+                if not tiny_file('traffic', mix)]
+    assert not missing, 'no tiny file under {} for {}'.format(TINY, missing)
+
+
 def test_tiny_configs_keep_every_key():
-    for name, tiny_cfg in TINY_CONFIGS.items():
-        with open(os.path.join(harness.BENCH_DIR, 'configs', name + '.json')) as f:
-            assert set(tiny_cfg) <= set(json.load(f))
+    """A tiny file only changes sizes that the configuration or mix has."""
+    spec = spec_with_extra_cells()
+    for config in spec['configs']:
+        tiny = tiny_file('configs', config['name'])
+        if tiny:
+            full = load_json(os.path.join(ROOT, config['file']))
+            assert set(load_json(tiny)) <= set(full), tiny
+    for mix_name in {w['traffic'] for w in spec['workloads']}:
+        tiny = tiny_file('traffic', mix_name)
+        if tiny:
+            mix = harness.stores.load_mix(os.path.join(harness.BENCH_DIR, 'traffic'), mix_name)
+            for block, values in load_json(tiny).items():
+                assert set(values) <= set(mix[block]), (tiny, block)
+
+
+def _named(entries, name):
+    return next(e for e in entries if e['name'] == name)
+
+
+def _tree_state(root):
+    """Size and modification time of every file of the benchmark under ``root``."""
+    state = {'BENCHMARK.json': os.stat(os.path.join(root, 'BENCHMARK.json'))}
+    for d, dirs, files in os.walk(os.path.join(root, 'benchmarks')):
+        dirs[:] = [x for x in dirs if x not in ('__pycache__', '.cache')]
+        for f in files:
+            path = os.path.join(d, f)
+            state[os.path.relpath(path, root)] = os.stat(path)
+    return {k: (st.st_size, st.st_mtime_ns) for k, st in state.items()}
+
+
+def test_new_config_needs_new_files_only(tmp_path):
+    """A later configuration is its file (and module) beside the others, its tiny
+    file and entries in BENCHMARK.json: no existing file changes, and its cell is
+    rehearsed and checked. Here a copy of the LM's configuration under another name."""
+    before = _tree_state(ROOT)
+    checkout = str(tmp_path / 'checkout')
+    os.makedirs(checkout)
+    _benchmark_copy(checkout)
+    source = 'cerebras_gpt_1p3b'
+    for kind in ('benchmarks/configs', TINY + '/configs'):
+        shutil.copy(os.path.join(ROOT, kind, source + '.json'),
+                    os.path.join(checkout, kind, 'throwaway_lm.json'))
+    spec = harness.load_spec(checkout)
+    config = dict(_named(spec['configs'], source), name='throwaway_lm',
+                  file='benchmarks/configs/throwaway_lm.json')
+    spec['configs'].append(config)
+    cell_name = 'throwaway.tokens_stream'
+    spec['workloads'].append({'name': cell_name, 'config': 'throwaway_lm',
+                              'traffic': 'tokens_stream', 'chips': 1,
+                              'why': 'a throwaway configuration'})
+    unlisted = harness.Cell(spec, cell_name, root=checkout)
+    assert 'tokens_per_s' not in {m['name'] for m in unlisted.end_to_end}
+    _named(spec['end_to_end'], 'tokens_per_s')['workloads'].append(cell_name)
+    with open(os.path.join(checkout, 'BENCHMARK.json'), 'w') as f:
+        json.dump(spec, f)
+
+    assert cell_name in [w['name'] for w in rehearsed_cells(checkout)]
+    rig = tiny_rig(str(tmp_path / 'tiny'), checkout)
+    cell = rig(cell_name)
+    # in float32 the tiny program reads round-off against the reference
+    cell.cfg = dict(cell.cfg, compute_dtype='float32')
+    result = harness.run(cell, 19, 0.5, cache_root=str(tmp_path / 'cache'))
+    assert result['attempted'] > 0 and 'tokens_per_s' in result['metrics']
+    assert result['correct'], result['checks']
+    assert _tree_state(ROOT) == before
