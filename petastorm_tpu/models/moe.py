@@ -20,6 +20,10 @@ here). Design is TPU-first, not a torch translation:
 The router runs in float32 (softmax stability); expert FFNs run in ``dtype``
 (bfloat16 by default, MXU-native). The load-balance auxiliary loss is sown into the
 ``'losses'`` collection — collect with :func:`moe_aux_total`.
+
+:class:`DroplessMoE` is DeepSeek-V3's layer beside it: sigmoid routing with a
+selection bias, no capacity (the assignments to the experts a chip holds are
+sorted by expert and run as grouped matmuls), and shared experts.
 """
 
 import math
@@ -29,6 +33,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from petastorm_tpu.ops.grouped_matmul import grouped_matmul
 
 
 def _capacity(num_tokens, num_experts, num_selected, capacity_factor):
@@ -255,6 +262,183 @@ def moe_drop_fractions(mutables):
     """Every MoE layer's latest capacity drop fraction (list of scalars; empty when
     the model has no MoE layers)."""
     return collect_sown(mutables, 'moe_drop_fraction')
+
+
+# ------------------------------------------------- sigmoid routing, dropless experts
+
+#: the names :class:`DroplessMoE` gives the experts it chose and its two
+#: grouped-matmul results: a remat policy that keeps them neither chooses again
+#: (a recomputed score a rounding apart could choose otherwise, and the kept
+#: results would then belong to other rows) nor runs the experts' matmuls again
+EXPERT_RESIDUALS = ('moe_chosen_experts', 'moe_expert_hidden', 'moe_expert_out')
+
+
+def sigmoid_topk_routing(logits, bias, num_selected, scaling):
+    """DeepSeek-V3's routing (``noaux_tc`` with one group): ``logits [S, X]``
+    float32 and the selection bias ``[X]`` -> ``(scores [S, X], experts [S, k],
+    weights [S, k])``. Scores are ``sigmoid(logits)``; the top ``k`` are chosen on
+    ``scores + bias``, and their weights are the scores without the bias,
+    normalised over the ``k`` and times ``scaling``."""
+    scores = jax.nn.sigmoid(logits)
+    _, experts = lax.top_k(scores + bias, num_selected)
+    experts = checkpoint_name(experts, EXPERT_RESIDUALS[0])
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return scores, experts, weights * scaling
+
+
+def _counts(ids, n):
+    """How often each of ``0..n-1`` occurs in ``ids`` (int32 ``[n]``), as a
+    compare-and-sum rather than a scatter with colliding indices."""
+    return jnp.sum(ids.reshape(-1, 1) == jnp.arange(n, dtype=ids.dtype), axis=0,
+                   dtype=jnp.int32)
+
+
+def sequence_balance_loss(scores, experts, batch):
+    """DeepSeek-V3's sequence-wise balance loss, before its weight alpha:
+    ``mean over sequences of sum_i f_i P_i`` with ``f_i = X / (k T) * (tokens of the
+    sequence routed to expert i)`` and ``P_i`` the sequence's mean of the scores
+    normalised over the ``X`` experts. ``scores [S, X]`` and ``experts [S, k]``
+    hold ``batch`` sequences of ``T = S / batch`` tokens, in order."""
+    n_tokens, n_exp = scores.shape
+    k = experts.shape[-1]
+    t = n_tokens // batch
+    share = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    mean_share = jnp.mean(share.reshape(batch, t, n_exp), axis=1)              # [B, X]
+    counts = jnp.sum(jax.nn.one_hot(experts, n_exp, dtype=jnp.float32),
+                     axis=1).reshape(batch, t, n_exp).sum(axis=1)               # [B, X]
+    f = counts * n_exp / (k * t)
+    return jnp.mean(jnp.sum(f * mean_share, axis=-1))
+
+
+def held_assignments(experts, first, held):
+    """The token-slot assignments that land on experts ``[first, first + held)``,
+    sorted by expert, in a static buffer of ``S * min(k, held)`` rows (the most a
+    chip holding ``held`` experts can receive: nothing is dropped). Returns
+    ``(rows, group_sizes, total, position)``: ``rows [M]`` the assignment (``token
+    * k + slot``) in each buffer row, valid for the first ``total``;
+    ``group_sizes [held]`` the rows of each held expert; ``position [S * k]`` each
+    assignment's buffer row, ``total`` or more where it is not held here."""
+    n_tokens, k = experts.shape
+    local = experts.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)     # the rest sort last
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    group_sizes = _counts(key, held)
+    total = jnp.sum(group_sizes)
+    position = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=jnp.int32), unique_indices=True)
+    return order[:n_tokens * min(k, held)], group_sizes, total, position
+
+
+class SwiGLU(nn.Module):
+    """``down(silu(gate(x)) * up(x))``, no biases, ``width`` wide."""
+
+    width: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,  # noqa: E731
+                                         name=name)
+        h = nn.silu(dense(self.width, 'gate_proj')(x)) * dense(self.width, 'up_proj')(x)
+        return dense(x.shape[-1], 'down_proj')(h)
+
+
+class DroplessMoE(nn.Module):
+    """DeepSeek-V3's routed experts and shared experts: ``[B, T, D] -> [B, T, D]``.
+
+    The float32 router scores all ``num_experts`` (:func:`sigmoid_topk_routing`
+    with the selection bias, a non-trained variable in the ``'router_state'``
+    collection); this layer holds experts ``[first_expert, first_expert +
+    held_experts)`` and computes, for every token, the part of the result that
+    they give, as one chip of an expert-parallel deployment does (on one chip
+    there is no exchange). Dispatch is dropless with static shapes: the
+    assignments to held experts are sorted by expert
+    (:func:`held_assignments`), the SwiGLU experts run as grouped matmuls
+    (:func:`~petastorm_tpu.ops.grouped_matmul.grouped_matmul`, whose time
+    follows the rows routed here) over them, and each token sums its rows,
+    weighted by gate. The chosen experts and the two matmuls' results are named
+    :data:`EXPERT_RESIDUALS`. ``shared_width`` adds the shared experts, one
+    SwiGLU applied to every token (0 for none).
+
+    Sown into ``'losses'``: ``moe_aux`` (:func:`sequence_balance_loss`; weight it
+    with :func:`moe_aux_total`), ``expert_rows`` (assignments computed here),
+    ``expert_load`` (tokens per expert over all of them) and ``router_bias``
+    (the bias the selection used); :func:`expert_counters` collects them. Applied
+    with ``'router_state'`` mutable, the bias then takes the aux-loss-free
+    update ``b + bias_step * sign(mean load - load)``.
+    """
+
+    num_experts: int
+    held_experts: int
+    num_selected: int
+    width: int
+    first_expert: int = 0
+    shared_width: int = 0
+    scaling: float = 1.0
+    bias_step: float = 0.0
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        batch, seqlen, d = x.shape
+        n_exp, held, k = self.num_experts, self.held_experts, self.num_selected
+        if not 0 <= self.first_expert <= n_exp - held or k > n_exp:
+            raise ValueError('experts [{}, {}) or top-{} do not fit {} experts'.format(
+                self.first_expert, self.first_expert + held, k, n_exp))
+        tokens = x.reshape(batch * seqlen, d)
+        bias = self.variable('router_state', 'bias', jnp.zeros, (n_exp,), jnp.float32)
+        with jax.named_scope('moe_route'):
+            logits = nn.Dense(n_exp, use_bias=False, dtype=jnp.float32,
+                              param_dtype=jnp.float32, precision=lax.Precision.HIGHEST,
+                              name='router')(tokens.astype(jnp.float32))
+            scores, experts, weights = sigmoid_topk_routing(
+                logits, bias.value, k, self.scaling)
+            load = _counts(experts, n_exp)                 # tokens per expert, all X
+        w_in = self.param('w_in', nn.initializers.lecun_normal(batch_axis=(0,)),
+                          (held, d, 2 * self.width), jnp.float32)
+        w_out = self.param('w_out', nn.initializers.lecun_normal(batch_axis=(0,)),
+                           (held, self.width, d), jnp.float32)
+        with jax.named_scope('moe_dispatch'):
+            rows, group_sizes, total, position = held_assignments(
+                experts, self.first_expert, held)
+            # rows past ``total`` belong to no expert: read as zero both ways, so
+            # whatever the grouped matmul leaves there reaches no token
+            valid = (jnp.arange(rows.shape[0]) < total)[:, None]
+            xs = jnp.where(valid, tokens.astype(self.dtype)[rows // k], 0)
+        with jax.named_scope('moe_experts'):
+            h = checkpoint_name(grouped_matmul(xs, w_in.astype(self.dtype), group_sizes),
+                                EXPERT_RESIDUALS[1])
+            gate, up = jnp.split(jnp.where(valid, h, 0), 2, axis=-1)
+            h = (nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(
+                self.dtype)
+            y = checkpoint_name(grouped_matmul(h, w_out.astype(self.dtype), group_sizes),
+                                EXPERT_RESIDUALS[2])
+            y = jnp.where(valid, y, 0)
+        with jax.named_scope('moe_combine'):
+            mine = (position < total)[:, None]
+            per_slot = jnp.where(mine, y[jnp.minimum(position, rows.shape[0] - 1)], 0)
+            out = jnp.sum(per_slot.reshape(batch * seqlen, k, d).astype(jnp.float32)
+                          * weights[..., None], axis=1)
+        if self.shared_width:
+            out = out + SwiGLU(self.shared_width, self.dtype, name='shared')(
+                x.astype(self.dtype)).reshape(batch * seqlen, d).astype(jnp.float32)
+
+        self.sow('losses', 'moe_aux', sequence_balance_loss(scores, experts, batch))
+        self.sow('losses', 'expert_rows', total)
+        self.sow('losses', 'expert_load', load)
+        self.sow('losses', 'router_bias', bias.value)
+        if self.is_mutable_collection('router_state') and not self.is_initializing():
+            bias.value = bias.value + self.bias_step * jnp.sign(
+                jnp.mean(load.astype(jnp.float32)) - load)
+        return out.reshape(batch, seqlen, d).astype(x.dtype)
+
+
+def expert_counters(mutables):
+    """Every :class:`DroplessMoE` layer's latest counters: ``{'expert_rows',
+    'expert_load', 'router_bias'}``, each a list with one entry per layer."""
+    return {name: collect_sown(mutables, name)
+            for name in ('expert_rows', 'expert_load', 'router_bias')}
 
 
 class MoEBlock(nn.Module):

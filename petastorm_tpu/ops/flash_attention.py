@@ -11,8 +11,11 @@ and dK/dV) that REMATERIALIZE the score blocks from Q/K and the saved LSE — th
 [T, T] attention matrix never exists in any pass, so training memory is O(T * block),
 sub-quadratic in sequence length.
 
-Falls back to the XLA path (:func:`petastorm_tpu.ops.ring_attention.dense_attention`)
-when shapes don't tile (T % block != 0, head_dim not lane-aligned). The kernels compile
+The value head may differ from the query/key head (multi-head latent attention:
+q/k 192, v 128); a head that is not lane-aligned but wider than one lane tile is
+taken whole by every block. Falls back to the XLA path
+(:func:`petastorm_tpu.ops.ring_attention.dense_attention`) when shapes don't tile
+(T % block != 0, a head narrower than 128 lanes and not aligned). The kernels compile
 for the TPU and run in Pallas interpret mode on the CPU backend only
 (:func:`pallas_interpret`), so CPU tests exercise the same kernel logic.
 
@@ -219,12 +222,13 @@ def _segment_operands(segments, block_q, block_k, heads, kv_outer, inner):
 
 def _flash_forward(q, k, v, causal, block_q, block_k, interpret, segments=None,
                    heads=None):
-    """q/k/v: [BH, T, D] -> (o: [BH, T, D], lse: [BH, T, 1] float32). ``segments``
-    is the [B, T] int32 packed-segment array."""
+    """q/k: [BH, T, D], v: [BH, T, Dv] -> (o: [BH, T, Dv], lse: [BH, T, 1]
+    float32). ``segments`` is the [B, T] int32 packed-segment array."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q.shape
+    dv = v.shape[-1]
     tk = k.shape[1]
     nq, nk = t // block_q, tk // block_k
     scale = d ** -0.5
@@ -235,7 +239,8 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret, segments=None,
     grid = (bh, nq, nk)
     kv = _inner_block(_causal_kv_block, causal, block_q, block_k)
     kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, kv(i, j), 0))
-    in_specs = [pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)), kspec, kspec]
+    vspec = pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, kv(i, j), 0))
+    in_specs = [pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)), kspec, vspec]
     operands = [q, k, v]
     if segmented:
         seg_specs, seg_operands = _segment_operands(segments, block_q, block_k,
@@ -244,16 +249,16 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret, segments=None,
         operands += seg_operands
     return pl.pallas_call(
         kernel,
-        out_shape=[jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
                    jax.ShapeDtypeStruct((bh, t, 1), jnp.float32)],
         grid=grid,
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        out_specs=[pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
                    pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANE), jnp.float32),   # running max (lane-replicated)
             pltpu.VMEM((block_q, _LANE), jnp.float32),   # running denominator
-            pltpu.VMEM((block_q, d), jnp.float32),       # output accumulator
+            pltpu.VMEM((block_q, dv), jnp.float32),      # output accumulator
         ],
         compiler_params=_compiler_params('parallel', 'parallel', 'arbitrary'),
         interpret=interpret,
@@ -354,11 +359,13 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest
 
 def _flash_backward(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
                     segments=None, heads=None):
-    """q/k/v/o/do: [BH, T, D], lse: [BH, T, 1] -> (dq, dk, dv), blockwise (no [T, T])."""
+    """q/k: [BH, T, D], v/o/do: [BH, T, Dv], lse: [BH, T, 1] -> (dq, dk, dv),
+    blockwise (no [T, T])."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q.shape
+    dv = v.shape[-1]
     nq, nk = t // block_q, t // block_k
     scale = d ** -0.5
     segmented = segments is not None
@@ -370,9 +377,11 @@ def _flash_backward(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
     kv = _inner_block(_causal_kv_block, causal, block_q, block_k)
     qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, kv(i, j), 0))
+    vspec = pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, kv(i, j), 0))
+    dospec = pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0))
     qcol = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
 
-    dq_in_specs = [qspec, kspec, kspec, qspec, qcol, qcol]
+    dq_in_specs = [qspec, kspec, vspec, dospec, qcol, qcol]
     dq_operands = [q, k, v, do, lse, delta]
     if segmented:
         seg_specs, seg_operands = _segment_operands(segments, block_q, block_k,
@@ -395,9 +404,11 @@ def _flash_backward(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
     # dK/dV iterate the OTHER way: outer over k-blocks, inner over q-blocks.
     qb = _inner_block(_causal_q_block, causal, block_q, block_k)
     kspec_o = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
+    vspec_o = pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, i, 0))
     qspec_i = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, qb(i, j), 0))
+    dospec_i = pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, qb(i, j), 0))
     qcol_i = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, qb(i, j), 0))
-    dkv_in_specs = [qspec_i, kspec_o, kspec_o, qspec_i, qcol_i, qcol_i]
+    dkv_in_specs = [qspec_i, kspec_o, vspec_o, dospec_i, qcol_i, qcol_i]
     dkv_operands = [q, k, v, do, lse, delta]
     if segmented:
         seg_specs, seg_operands = _segment_operands(segments, block_q, block_k,
@@ -409,20 +420,28 @@ def _flash_backward(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
                           block_q=block_q, block_k=block_k, scale=scale,
                           precision=precision),
         out_shape=[jax.ShapeDtypeStruct((bh, t, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, t, d), v.dtype)],
+                   jax.ShapeDtypeStruct((bh, t, dv), v.dtype)],
         grid=(bh, nk, nq),
         in_specs=dkv_in_specs,
-        out_specs=[kspec_o, kspec_o],
+        out_specs=[kspec_o, vspec_o],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, dv), jnp.float32)],
         compiler_params=_compiler_params('parallel', 'parallel', 'arbitrary'),
         interpret=interpret,
     )(*dkv_operands)
     return dq, dk, dv
 
 
+def _head_fits(d):
+    """Whether the kernels take a head of ``d`` lanes: lane-aligned, or wider than
+    one lane tile, its blocks then spanning the array's whole last dimension (which
+    the TPU block rule allows; MLA's 192-wide q/k head is one). A narrower
+    unaligned head keeps the dense path."""
+    return d % _LANE == 0 or d > _LANE
+
+
 def _tiles(t, d, block_q, block_k):
-    return t % block_q == 0 and t % block_k == 0 and d % _LANE == 0
+    return t % block_q == 0 and t % block_k == 0 and _head_fits(d)
 
 
 # 'auto' preference order: the largest that divides T. On one TPU v5e (bf16,
@@ -446,19 +465,22 @@ def _resolve_blocks(t, block_q, block_k):
     return one(block_q), one(block_k)
 
 
-def _dispatch(q, k, block_q, block_k):
+def _dispatch(q, k, block_q, block_k, v=None):
     """Single resolve-then-decide point shared by every fwd/bwd path:
-    ``(use_pallas, resolved_block_q, resolved_block_k)``."""
+    ``(use_pallas, resolved_block_q, resolved_block_k)``. ``v``, where given,
+    may have a value head other than q's and k's."""
     b, t, h, d = q.shape
     block_q, block_k = _resolve_blocks(t, block_q, block_k)
-    return (_tiles(t, d, block_q, block_k) and t == k.shape[1],
+    value_fits = v is None or _head_fits(v.shape[-1])
+    return (_tiles(t, d, block_q, block_k) and value_fits and t == k.shape[1],
             block_q, block_k)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(q, k, v, causal=False, block_q='auto', block_k='auto'):
     """Flash attention over ``[B, T, H, D]`` inputs (same layout as
-    :func:`~petastorm_tpu.ops.ring_attention.dense_attention`). Exact; both passes run
+    :func:`~petastorm_tpu.ops.ring_attention.dense_attention`); ``v`` may have a
+    head ``Dv`` of its own, and the result is then ``[B, T, H, Dv]``. Exact; both passes run
     as Pallas TPU kernels when shapes tile (XLA dense fallback otherwise), with
     O(T * block) memory in forward AND backward. Block sizes default to
     ``'auto'``: the largest of 512, 256 and 128 that divides T — pass ints to
@@ -488,7 +510,7 @@ def _attention_impl(q, k, v, causal, block_q, block_k):
 
 def _fwd(q, k, v, causal, block_q, block_k):
     from petastorm_tpu.ops.ring_attention import dense_attention
-    use, block_q, block_k = _dispatch(q, k, block_q, block_k)
+    use, block_q, block_k = _dispatch(q, k, block_q, block_k, v)
     if not use:
         return dense_attention(q, k, v, causal=causal), (q, k, v, None, None, None)
     b, t, h, d = q.shape
@@ -533,7 +555,7 @@ def flash_attention_segmented(q, k, v, segments, causal=False, block_q='auto',
 
 
 def _seg_fwd(q, k, v, segments, causal, block_q, block_k):
-    use, block_q, block_k = _dispatch(q, k, block_q, block_k)
+    use, block_q, block_k = _dispatch(q, k, block_q, block_k, v)
     if not use:
         from petastorm_tpu.ops.packing import masked_dense_attention, segment_mask
         mask = segment_mask(segments, segments, causal=causal)

@@ -156,7 +156,8 @@ def segment_causal_attention(segments, use_flash=False, block_q='auto',
                 import warnings
                 warnings.warn(
                     'segment_causal_attention(use_flash=True): shapes {}x{} head_dim'
-                    ' {} do not tile (need T % block == 0 and head_dim % 128 == 0); '
+                    ' {} do not tile (need T % block == 0 and a head_dim that is a '
+                    'multiple of 128 or wider than 128); '
                     'running the O(T^2) masked dense path instead.'.format(
                         q.shape[1], k.shape[1], q.shape[-1]), stacklevel=2)
             return flash_attention_segmented(q, k, v, segments, True,

@@ -39,7 +39,8 @@ class TestRingAttention:
 
 class TestFlashAttention:
     """The Pallas kernel runs in interpret mode on the CPU test platform — same kernel
-    body as on hardware (tile-aligned shapes only: T % block == 0, D % 128 == 0)."""
+    body as on hardware (tiling shapes only: T % block == 0, each head a multiple of
+    128 lanes or wider than 128)."""
 
     @pytest.mark.parametrize('causal', [False, True])
     def test_matches_dense(self, causal):
@@ -77,6 +78,71 @@ class TestFlashAttention:
         for gf, gd, name in zip(g_flash, g_dense, 'qkv'):
             np.testing.assert_allclose(np.asarray(gf), np.asarray(gd),
                                        atol=5e-4, rtol=5e-4, err_msg='d' + name)
+
+    @pytest.mark.parametrize('causal', [False, True])
+    def test_latent_heads_match_dense(self, causal):
+        """Multi-head latent attention's heads: q and k 192 wide (not lane-aligned,
+        taken whole by each block), v 128. The kernels run (2 x 2 blocks of 128)
+        and match dense attention in value and in dq, dk, dv."""
+        from petastorm_tpu.ops.flash_attention import _use_pallas, flash_attention
+        rng = np.random.RandomState(5)
+        q, k = (jnp.asarray(rng.randn(1, 256, 2, 192) * 0.5, dtype=jnp.float32)
+                for _ in range(2))
+        v = jnp.asarray(rng.randn(1, 256, 2, 128) * 0.5, dtype=jnp.float32)
+        assert _use_pallas(q, k, 128, 128)
+        out = flash_attention(q, k, v, causal, 128, 128)
+        assert out.shape == (1, 256, 2, 128)
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(dense_attention(q, k, v, causal=causal)),
+                                   atol=2e-4, rtol=2e-4)
+
+        def loss(fn):
+            return lambda a, b_, c: (fn(a, b_, c) * jnp.cos(
+                jnp.arange(out.size, dtype=jnp.float32).reshape(out.shape))).sum()
+
+        g_flash = jax.grad(loss(lambda a, b_, c: flash_attention(a, b_, c, causal,
+                                                                 128, 128)),
+                           argnums=(0, 1, 2))(q, k, v)
+        g_dense = jax.grad(loss(lambda a, b_, c: dense_attention(a, b_, c,
+                                                                 causal=causal)),
+                           argnums=(0, 1, 2))(q, k, v)
+        for gf, gd, name in zip(g_flash, g_dense, 'qkv'):
+            assert gf.shape == gd.shape, name
+            np.testing.assert_allclose(np.asarray(gf), np.asarray(gd),
+                                       atol=5e-4, rtol=5e-4, err_msg='d' + name)
+
+    @pytest.mark.parametrize('d,dv', [(128, 128), (192, 128)], ids=['equal_heads', 'mla'])
+    def test_pallas_call_shapes(self, d, dv):
+        """The forward, dq and dk/dv kernels' operand and result shapes, as the
+        trace readers match them: with equal heads exactly the three-tensor
+        signatures they always had; with a value head of its own, v, o and do
+        (and dv) at that head, q, k, dq and dk at theirs."""
+        from petastorm_tpu.ops.flash_attention import flash_attention
+        q = jax.ShapeDtypeStruct((2, 256, 2, d), jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((2, 256, 2, dv), jnp.bfloat16)
+
+        def loss(a, b_, c):
+            return flash_attention(a, b_, c, True, 128, 128).astype(jnp.float32).sum()
+
+        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, v)
+        calls = [([tuple(x.aval.shape) for x in e.invars],
+                  [tuple(x.aval.shape) for x in e.outvars])
+                 for e in jaxpr.eqns if e.primitive.name == 'pallas_call']
+        qk, vv, col = (4, 256, d), (4, 256, dv), (4, 256, 1)
+        assert calls == [([qk, qk, vv], [vv, col]),
+                         ([qk, qk, vv, vv, col, col], [qk]),
+                         ([qk, qk, vv, vv, col, col], [qk, vv])]
+
+    def test_head_widths_that_take_the_kernels(self):
+        """Lane-aligned heads and heads wider than a lane tile take the kernels, for
+        q/k and for v alike; a narrower unaligned head keeps the dense path."""
+        from petastorm_tpu.ops.flash_attention import _dispatch
+        mk = lambda d: jnp.zeros((1, 256, 2, d), jnp.float32)  # noqa: E731
+        assert _dispatch(mk(192), mk(192), 'auto', 'auto', mk(128))[0]
+        assert _dispatch(mk(128), mk(128), 'auto', 'auto', mk(192))[0]
+        assert _dispatch(mk(256), mk(256), 'auto', 'auto', mk(256))[0]
+        assert not _dispatch(mk(64), mk(64), 'auto', 'auto', mk(64))[0]
+        assert not _dispatch(mk(192), mk(192), 'auto', 'auto', mk(96))[0]
 
     @pytest.mark.parametrize('causal', [False, True])
     def test_segmented_matches_masked_dense(self, causal):

@@ -127,3 +127,41 @@ def test_dct_decode_program_compiles(one_chip):
     coeffs = _spec(one_chip, (64, 28, 28, 8, 8, 3), jnp.int16)
     hlo = _compile(lambda c: dct_decode_images_jax(c, quality=90), coeffs)
     assert 'u8[64,224,224,3]' in hlo
+
+
+def test_moonlight_step_fits_one_v5e(one_chip, monkeypatch):
+    """The Moonlight-16B-A3B cell's train step at its full cut widths (6 layers,
+    8 of 64 experts held, T = 8,192), compiled once for the chip: it fits one
+    v5e's memory, its attention runs the flash kernels at q/k 192 and value 128
+    (a forward per layer and its rematerialised copy, one dq and one dk/dv) with
+    no dense [T, T] fallback, and its experts the grouped-matmul kernels, whose
+    forwards are not run again."""
+    from benchmarks import common, harness
+    from benchmarks.metrics import mla_flash_roofline
+    monkeypatch.setattr(fa, 'pallas_interpret', lambda: False)  # compile for the chip
+    cell = harness.Cell(harness.load_spec(), 'moonlight16b.tokens_8k')
+    cfg = cell.cfg
+    program = cell.module.Program(cfg)
+    put = lambda tree: jax.tree.map(  # noqa: E731
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
+    state = jax.eval_shape(program.init, common.weight_key(1))
+    batch = {'id': jax.ShapeDtypeStruct((cell.global_batch,), jnp.int32),
+             'tokens': jax.ShapeDtypeStruct((cell.global_batch, cfg['seq_len']), jnp.int32)}
+    compiled = jax.jit(program.step, donate_argnums=0).lower(put(state), put(batch)).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15 * 2 ** 30
+    lines = [line.strip() for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    layers, t = cfg['num_hidden_layers'], cfg['seq_len']
+    bh = cell.global_batch * cfg['num_attention_heads']
+    head = (bh, t, cfg['qk_nope_head_dim'] + cfg['qk_rope_head_dim'], cfg['v_head_dim'], 2)
+    calls = sorted(c for c in map(mla_flash_roofline.kernel_call, lines) if c)
+    assert calls == sorted([('fwd',) + head] * 2 * layers + [('bwd_dq',) + head] * layers
+                           + [('bwd_dkv',) + head] * layers)
+    assert '{},{}]'.format(t, t) not in compiled.as_text()
+    # each routed layer: two forwards and two input gradients on megablox's gmm
+    # kernel, two weight gradients on its tgmm; remat keeps the forwards' results,
+    # so none runs again
+    kernels = [line.lstrip('%').split(' ')[0].split('.')[0] for line in lines]
+    routed = layers - cfg['first_k_dense_replace']
+    assert (kernels.count('gmm'), kernels.count('tgmm')) == (4 * routed, 2 * routed)
